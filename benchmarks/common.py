@@ -10,35 +10,8 @@ Two measurement paths:
 """
 from __future__ import annotations
 
-import os
 import sys
 import time
-
-
-def enable_compile_cache() -> str | None:
-    """Point JAX's persistent compilation cache at ``$REPRO_COMPILE_CACHE``.
-
-    Opt-in and best-effort: unset env -> no-op, and any failure to enable
-    (old jax, read-only dir) degrades to cold compiles rather than
-    breaking the benchmark run. Returns the cache dir when enabled. CI
-    smoke jobs set the env so repeat runs skip XLA compilation entirely.
-    """
-    d = os.environ.get("REPRO_COMPILE_CACHE")
-    if not d:
-        return None
-    try:
-        import jax
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        # default threshold skips sub-second compiles; the engine's small
-        # shape buckets are exactly those, so cache everything
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        return d
-    except Exception:
-        return None
-
-
-enable_compile_cache()
 
 from repro.core.lock import (simulate, extract, simulate_aria, extract_aria,
                              WorkloadSpec, CostModel)

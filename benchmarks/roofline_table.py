@@ -10,13 +10,10 @@ once, so the numbers are ≈ per engine iteration — and place each
 executable against the ``launch/roofline.py`` hardware model
 (``dist_to_peak`` = bound-time / compute-time; large = memory-bound).
 
-Caveat (DESIGN.md §12): the hardware model is the TPU-v5e-like chip from
-``launch/roofline.py``; on the CPU hosts that run this table the
-absolute times are hypothetical — the *ratios* (arithmetic intensity,
-bottleneck, per-entry-point growth with T and L) are the signal, and the
-point of the table is that every engine entry point sits deep in the
-memory-bound regime: the future Pallas kernel's job is fusing the T×L
-scans, not adding FLOPs.
+The entry points are compiled ahead of time for one chip of a described
+TPU v5e topology (the TPU compiler ships with JAX; no chip needs to be
+attached) and priced with that chip's published peaks
+(``launch/roofline.PEAKS``), so the rows mean the same on every host.
 """
 import glob
 import json
@@ -66,22 +63,39 @@ def _cost_totals(compiled) -> tuple[float, float]:
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 
-def _stack(tree, g: int):
+def _on(device, tree, lanes=None):
+    """Shape-only view of ``tree`` on ``device``, optionally with a
+    leading lane axis."""
     import jax
-    import jax.numpy as jnp
-    return jax.tree.map(lambda x: jnp.stack([x] * g), tree)
+    from jax.sharding import SingleDeviceSharding
+    lead = () if lanes is None else (lanes,)
+    one = SingleDeviceSharding(device)
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        lead + x.shape, x.dtype, sharding=one), tree)
+
+
+def _v5e_chip():
+    """One chip of a described (not attached) v5e topology."""
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no logs on disk
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
 
 
 def engine_rows(quick=True):
     """AOT roofline rows for the engine entry points, per (protocol,T,L)."""
+    import jax
     import jax.numpy as jnp
 
+    from repro.compile_cache import cache_off
     from repro.core.lock import (CostModel, EngineConfig, WorkloadSpec,
                                  protocol_params, split_config,
                                  init_state_dyn)
     from repro.core.lock import engine as E
-    from repro.launch.roofline import PEAK_FLOPS, HBM_BW, collective_bytes
+    from repro.launch.roofline import PEAKS, V5E, collective_bytes
     from repro.obs import compile_log
+
+    chip, peaks = _v5e_chip(), PEAKS[V5E]
 
     grid = [("mysql", 64, 4), ("brook2pl", 64, 4)]
     if not quick:
@@ -96,27 +110,30 @@ def engine_rows(quick=True):
                                   n_rows=512),
             n_threads=T, horizon=200_000)
         stat, dp = split_config(cfg)
-        s0 = init_state_dyn(stat, dp)
-        until = jnp.asarray(100_000, jnp.int32)
-        entries = [("run_dyn", E._run_dyn, (stat, dp, s0))]
+        s0 = jax.eval_shape(lambda d: init_state_dyn(stat, d), dp)
+        until = jax.ShapeDtypeStruct((), jnp.int32)
+        entries = [("run_dyn", E._run_dyn,
+                    (stat, _on(chip, dp), _on(chip, s0)))]
         # batched + segmented entry points: mysql always; the rest of the
         # grid only in full mode (each AOT compile is seconds on 1 core)
         if proto == "mysql" or not quick:
             entries += [
                 ("run_batch", E._run_batch,
-                 (stat, _stack(dp, G), _stack(s0, G))),
+                 (stat, _on(chip, dp, G), _on(chip, s0, G))),
                 ("run_seg_batch", E._run_seg_batch,
-                 (stat, _stack(dp, G), _stack(s0, G), _stack(until, G))),
+                 (stat, _on(chip, dp, G), _on(chip, s0, G),
+                  _on(chip, until, G))),
             ]
         for ename, fn, fargs in entries:
             t0 = time.perf_counter()
-            compiled = fn.lower(*fargs).compile()
+            with cache_off():
+                compiled = fn.lower(*fargs).compile()
             compile_s = time.perf_counter() - t0
             flops, byts = _cost_totals(compiled)
             hlo = compiled.as_text()
             coll = sum(collective_bytes(hlo).values())
-            t_c = flops / PEAK_FLOPS
-            t_m = byts / HBM_BW
+            t_c = flops / peaks.flops
+            t_m = byts / peaks.hbm_bw
             t_bound = max(t_c, t_m)
             bottleneck = "compute" if t_c >= t_m else "memory"
             dist = (t_bound / t_c) if t_c > 0 else float("inf")

@@ -13,6 +13,7 @@ import argparse
 import json
 import sys
 import time
+import traceback
 
 
 def _peak_rss_mb() -> float:
@@ -89,7 +90,7 @@ def merge_only_doc(doc: dict, path: str) -> tuple[dict, str | None]:
         return doc, f"merge_skipped={type(e).__name__}: {e}"
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
@@ -98,6 +99,8 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     quick = not args.full
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (analysis_gate, common, compaction_bench,
                    fig02_motivation, fig06_ablation, fig07_mix,
                    fig08_scalability, fig09_sync, fig10_abort_skew,
@@ -139,7 +142,8 @@ def main() -> None:
         tele0 = compile_log.snapshot()
         try:
             rows = mod.run(quick=quick) or []
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # keep going; the exit code reports it
+            traceback.print_exc()
             print(f"{name}_ERROR,0,{type(e).__name__}:{e}")
             common.pop_sweep_stats()    # drop partial accounting
             tele = compile_log.delta(tele0)
@@ -198,7 +202,11 @@ def main() -> None:
             json.dump(out, f, indent=1)
             f.write("\n")
         print(f"# json_written={args.json}")
+    errored = [n for n, m in doc["modules"].items() if "error" in m]
+    if errored:
+        print(f"# modules_errored={','.join(errored)}")
+    return 1 if errored else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

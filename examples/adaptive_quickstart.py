@@ -19,6 +19,7 @@ from repro.adaptive import (EpsilonGreedyPolicy, FixedPolicy, GovernorCell,
                             QueueRulePolicy, preset_timeline, run_governed)
 from repro.core.lock import CostModel, WorkloadSpec, skew_ramp
 from repro.sweep import save_results, summarize
+from repro.compile_cache import enable_compile_cache
 
 CM = CostModel(op_exec=20, commit_base=30)   # lock-manager-bound OLTP
 
@@ -59,4 +60,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

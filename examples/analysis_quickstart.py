@@ -32,6 +32,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.analysis import cli as acli
 from repro.analysis import isolation, jaxpr_lint
 from repro.core.lock import WorkloadSpec
+from repro.compile_cache import enable_compile_cache
 
 WL = WorkloadSpec(kind="zipf", n_rows=256, txn_len=4, zipf_s=1.1, seed=1)
 
@@ -76,4 +77,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

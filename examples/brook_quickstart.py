@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core.lock import (HALT, WorkloadSpec, chop, extract, simulate)
 from repro.sweep import grid, run_sweep
+from repro.compile_cache import enable_compile_cache
 
 W = WorkloadSpec(kind="zipf", txn_len=4, n_rows=2048, zipf_s=0.9)
 TPCC = WorkloadSpec(kind="tpcc", txn_len=10, n_rows=8192,
@@ -72,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

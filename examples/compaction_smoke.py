@@ -12,6 +12,7 @@ as the compaction-smoke job.
 """
 from repro.core.lock import WorkloadSpec, extract, simulate
 from repro.sweep import point, run_sweep
+from repro.compile_cache import enable_compile_cache
 
 ZIPF = WorkloadSpec(kind="zipf", txn_len=2, n_rows=512, zipf_s=0.9)
 HORIZON = 60_000
@@ -50,4 +51,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -36,6 +36,7 @@ from repro.core.lock import WorkloadSpec, simulate
 from repro.obs import (blame_table, check_ca_conservation, events_host,
                        hotspot_report, simulate_traced)
 from repro.serving import ServeCell, ServingMetrics, poisson, serve
+from repro.compile_cache import enable_compile_cache
 
 WL = WorkloadSpec(kind="zipf", txn_len=8, n_rows=2048, zipf_s=1.2)
 T, HORIZON = 64, 120_000
@@ -80,4 +81,5 @@ def main(out_path="hotspot_metrics.prom"):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(*sys.argv[1:2])

@@ -29,6 +29,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.core.lock import (CostModel, EngineConfig, WorkloadSpec,
                              protocol_params)
 from repro.obs import compile_log, profile_step, rank_table
+from repro.compile_cache import enable_compile_cache
 
 WL = WorkloadSpec(kind="hotspot_update", txn_len=4, n_rows=512)
 
@@ -52,4 +53,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -14,6 +14,7 @@ from repro.models import lm_spec, init_params
 from repro.optim import adamw
 from repro.data import DataConfig, init_state, make_batch
 from repro.launch.steps import make_train_step
+from repro.compile_cache import enable_compile_cache
 
 
 def cc_demo():
@@ -47,5 +48,6 @@ def train_demo(steps=20):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     cc_demo()
     train_demo()

@@ -7,6 +7,8 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.launch.serve import main
+from repro.compile_cache import enable_compile_cache
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

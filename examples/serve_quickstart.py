@@ -16,6 +16,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core.lock import WorkloadSpec
 from repro.serving import ServeCell, poisson, serve
+from repro.compile_cache import enable_compile_cache
 
 HOT = WorkloadSpec(kind="hotspot_update", txn_len=2, n_rows=4096)
 T = 32
@@ -70,4 +71,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ write ratio), add ``p_abort=[...]`` / ``costs=[...]`` axes, or
 """
 from repro.core.lock import WorkloadSpec
 from repro.sweep import grid, run_sweep, summarize, save_results
+from repro.compile_cache import enable_compile_cache
 
 HOT = WorkloadSpec(kind="hotspot_update", txn_len=1, n_rows=512)
 
@@ -30,4 +31,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -39,6 +39,7 @@ from repro.core.lock import WorkloadSpec, simulate, extract
 from repro.obs import (EV_VICTIM, blame_table, check_conservation,
                        critical_path, dump_chrome_trace, events_host,
                        make_trace, simulate_traced, wait_profile)
+from repro.compile_cache import enable_compile_cache
 
 # zipf with multi-op transactions: lock-order cycles actually form, so
 # mysql's detection walk has victims to kill (hotspot_update txn_len=1
@@ -121,4 +122,5 @@ def jax_leaves(tree):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(*sys.argv[1:2])

@@ -14,6 +14,7 @@ import dataclasses
 from repro.configs.base import ModelConfig
 import repro.configs as configs
 from repro.launch import train as train_mod
+from repro.compile_cache import enable_compile_cache
 
 # ~100M params: 640 width, 8 layers, GQA 8/4
 CONFIG_100M = ModelConfig(
@@ -55,4 +56,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

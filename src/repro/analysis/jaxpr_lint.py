@@ -301,7 +301,7 @@ def default_entry_points() -> list[EntryPoint]:
 
         def _segment_sums_g16(seg_ids, updates):
             # num_groups is a shape argument, fixed like the other shapes
-            return gk.segment_sums(seg_ids, updates, 16)
+            return gk.segment_sums(seg_ids, updates, 16, interpret=True)
 
         eps += [
             EntryPoint("kernels.flash_attention", fo.flash_attention,

@@ -24,10 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 DEF_BG = 128      # group rows per block   (MXU lane dim)
 DEF_BD = 256      # feature columns per block
@@ -58,12 +54,13 @@ def _seg_matmul_kernel(seg_ref, upd_ref, out_ref):
                                     "interpret"))
 def segment_sums(seg_ids: jnp.ndarray, updates: jnp.ndarray,
                  num_groups: int, bg: int = DEF_BG, bd: int = DEF_BD,
-                 bn: int = DEF_BN, interpret: bool = True) -> jnp.ndarray:
+                 bn: int = DEF_BN, interpret: bool = False) -> jnp.ndarray:
     """Blocked one-hot segment sum. seg_ids: (N,) sorted (any order works —
     sortedness only improves one-hot block sparsity); updates: (N, D).
 
     Returns (num_groups, D) f32. Rows with seg_id outside [0, num_groups)
-    are dropped.
+    are dropped. Compiled for the TPU unless ``interpret=True`` (the CPU
+    tests' Pallas interpreter).
     """
     N, D = updates.shape
     bg = min(bg, max(8, num_groups))
@@ -88,7 +85,7 @@ def segment_sums(seg_ids: jnp.ndarray, updates: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bg, bd), lambda g, d, n: (g, d)),
         out_shape=jax.ShapeDtypeStruct((G, Dp), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seg_ids[None, :], updates)

@@ -25,7 +25,7 @@ def grouped_scatter_apply(table: jnp.ndarray, ids: jnp.ndarray,
                           updates: jnp.ndarray,
                           threshold: int = DEFAULT_THRESHOLD,
                           max_hot: int = 256,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool = False) -> jnp.ndarray:
     """Apply (ids -> updates) into table rows, hot rows via the kernel.
 
     max_hot: static bound on distinct hot rows per batch (hot rows are by

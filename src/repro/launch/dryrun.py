@@ -37,7 +37,7 @@ from repro.distributed.sharding import _axis_size, set_activation_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (make_train_step, make_prefill_step,
                                 make_serve_step)
-from repro.launch.roofline import (collective_bytes, Roofline,
+from repro.launch.roofline import (V5E, collective_bytes, Roofline,
                                    model_flops_estimate,
                                    analytic_hbm_bytes)
 
@@ -226,6 +226,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         coll_bytes=float(sum(coll.values())),
         coll_breakdown=coll,
         model_flops=model_flops_estimate(cfg, shape),
+        device_kind=V5E,
     )
     result = {
         "arch": arch, "shape": shape_name,

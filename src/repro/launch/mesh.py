@@ -10,17 +10,11 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across API generations: jax >= 0.5 takes (and some
-    sharding paths want) explicit Auto axis_types; 0.4.x has neither the
-    kwarg nor ``jax.sharding.AxisType`` — where every mesh axis is Auto
-    already. Regression caught by tests/test_sweep.py's forced-multi-
-    device subprocess: lane sharding never engaged on 0.4.x because mesh
-    construction itself raised."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto``: jax defaults to
+    ``Explicit`` axes, under which sharded steps refuse implicit
+    resharding."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

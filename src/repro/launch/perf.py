@@ -25,7 +25,7 @@ from repro.distributed import ResolveReport, data_axes
 from repro.distributed.sharding import _axis_size, set_activation_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch import dryrun as dr
-from repro.launch.roofline import (collective_bytes, Roofline,
+from repro.launch.roofline import (V5E, collective_bytes, Roofline,
                                    model_flops_estimate,
                                    analytic_hbm_bytes)
 
@@ -97,7 +97,8 @@ def run_variant(arch, shape_name, multi_pod, overrides, tag):
         bytes_accessed=analytic_hbm_bytes(cfg, shape, chips, pbytes,
                                           obytes, param_shards=shards),
         coll_bytes=float(sum(coll.values())), coll_breakdown=coll,
-        model_flops=model_flops_estimate(cfg, shape))
+        model_flops=model_flops_estimate(cfg, shape),
+        device_kind=V5E)
     gb = 1 << 30
     print(f"[perf:{tag}] {arch} x {shape_name} x {roof.mesh}: "
           f"t_comp={roof.t_compute*1e3:.2f}ms "

@@ -11,7 +11,7 @@ import sys
 import jax.numpy as jnp
 
 from repro.configs import get_config, SHAPES
-from repro.launch.roofline import (Roofline, analytic_hbm_bytes,
+from repro.launch.roofline import (V5E, Roofline, analytic_hbm_bytes,
                                    model_flops_estimate)
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -59,7 +59,8 @@ def main():
                 cfg, shape, chips, pbytes, obytes, param_shards=shards),
             coll_bytes=r["roofline"]["coll_bytes_per_chip"],
             coll_breakdown=r["roofline"].get("coll_breakdown", {}),
-            model_flops=model_flops_estimate(cfg, shape))
+            model_flops=model_flops_estimate(cfg, shape),
+            device_kind=V5E)
         r["roofline"] = roof.row()
         with open(path, "w") as f:
             json.dump(r, f, indent=1, default=str)

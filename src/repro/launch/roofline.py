@@ -1,7 +1,8 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
-Hardware model (TPU v5e-like, per chip):
-    197 TFLOP/s bf16  |  819 GB/s HBM  |  ~50 GB/s/link ICI (x3 links)
+Hardware model: the published per-chip peaks in :data:`PEAKS`, keyed by
+``jax.Device.device_kind``. A device that is not in the table is an error
+(:func:`chip_peaks`), never a default.
 
 Terms (seconds, per step, per chip):
     compute    = HLO_FLOPs / (chips * PEAK)
@@ -21,9 +22,32 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link (one direction)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float                # bf16 FLOP/s per chip
+    hbm_bw: float               # HBM bytes/s per chip
+    ici_bw: float               # interconnect bytes/s per link
+    source: str
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s interchip interconnect (200 GB/s over four links).
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                   source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Published peaks of one chip; raises for a device not in PEAKS."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -103,21 +127,23 @@ class Roofline:
     coll_bytes: float            # per-device collective bytes
     coll_breakdown: Dict[str, int]
     model_flops: float           # 6*N*D useful flops (global)
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
-    ici_bw: float = ICI_BW
+    device_kind: str             # chip the terms are priced on (PEAKS)
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / self.peak_flops
+        return self.flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / self.hbm_bw
+        return self.bytes_accessed / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / self.ici_bw
+        return self.coll_bytes / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -140,7 +166,7 @@ class Roofline:
         """Upper bound on achievable MFU given the dominant term."""
         if self.t_bound == 0:
             return 0.0
-        return (self.model_flops / self.chips / self.peak_flops) \
+        return (self.model_flops / self.chips / self.peaks.flops) \
             / self.t_bound
 
     def row(self) -> dict:

@@ -18,20 +18,6 @@ from jax import lax
 BLOCK = 2048  # quantization block (per-block scale)
 
 
-def _axis_size(axis_name) -> int:
-    """lax.axis_size, with a fallback for jax<=0.4.37 (axis env lookup —
-    core.axis_frame already resolves to the size there)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax import core
-    return core.axis_frame(axis_name)
-
-
-# pvary marks values as device-varying for shard_map's replication checks;
-# older jax has no such notion, so identity is the correct fallback.
-_pvary = getattr(lax, "pvary", lambda x, names: x)
-
-
 def _blocked(x: jnp.ndarray):
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % BLOCK
@@ -58,10 +44,10 @@ def quantized_psum(x: jnp.ndarray, axis_name: str, residual=None):
     of *quantized* contributions; each device's quantization error stays
     local in `residual` and is re-injected next call.
     """
-    n = _axis_size(axis_name)
-    xf = _pvary(x.astype(jnp.float32), (axis_name,))
+    n = lax.axis_size(axis_name)
+    xf = lax.pcast(x.astype(jnp.float32), (axis_name,), to="varying")
     if residual is not None:
-        xf = xf + _pvary(residual, (axis_name,))
+        xf = xf + lax.pcast(residual, (axis_name,), to="varying")
     blocks, pad = _blocked(xf)
     q, s = quantize(blocks)
     err = (blocks - dequantize(q, s)).reshape(-1)

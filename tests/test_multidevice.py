@@ -18,7 +18,7 @@ import pytest
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 FULL = os.environ.get("REPRO_MULTIDEVICE_FULL") == "1"
-N_DEV, MESH, N_STEPS = (8, "(4, 2)", 2) if FULL else (4, "(2, 2)", 1)
+N_DEV, N_STEPS = (8, 2) if FULL else (4, 1)
 
 
 def run_sub(code: str, timeout=600):
@@ -42,7 +42,8 @@ HEADER = (
     "from repro.distributed import param_shardings, batch_shardings;"
     "from repro.distributed.sharding import set_activation_mesh;"
     "from repro.launch.steps import make_train_step;"
-    f"mesh = jax.make_mesh({MESH}, ('data', 'model'));"
+    "from repro.launch.mesh import make_host_mesh;"
+    "mesh = make_host_mesh(model_axis=2);"
 )
 
 

@@ -111,14 +111,13 @@ class TestOptim:
         assert self._quad_losses(bits) < 0.5
 
     def test_quantized_psum_single_device(self):
-        # axis size 1: quantization error only. check_rep=False because
+        # axis size 1: quantization error only. check_vma=False because
         # the manual ring's replication cannot be statically inferred.
         mesh = jax.make_mesh((1,), ("d",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         x = jnp.linspace(-1, 1, 4096)
-        f = shard_map(lambda v: quantized_psum(v, "d")[0], mesh,
-                      in_specs=P(), out_specs=P(), check_rep=False)
+        f = jax.shard_map(lambda v: quantized_psum(v, "d")[0], mesh=mesh,
+                          in_specs=P(), out_specs=P(), check_vma=False)
         np.testing.assert_allclose(f(x), x, atol=2e-2)
 
     def test_quantized_psum_multidevice_subprocess(self):
@@ -129,14 +128,14 @@ class TestOptim:
             "os.environ['XLA_FLAGS']="
             "'--xla_force_host_platform_device_count=8';"
             "import jax, jax.numpy as jnp, numpy as np;"
-            "from jax.experimental.shard_map import shard_map;"
             "from jax.sharding import PartitionSpec as P;"
             "from repro.optim import quantized_psum;"
             "mesh = jax.make_mesh((8,), ('d',));"
             "x = jnp.arange(8 * 512, dtype=jnp.float32)"
             ".reshape(8, 512) / 1000.0;"
-            "f = shard_map(lambda v: quantized_psum(v[0], 'd')[0][None],"
-            " mesh, in_specs=P('d'), out_specs=P('d'), check_rep=False);"
+            "f = jax.shard_map(lambda v: quantized_psum(v[0], 'd')[0][None],"
+            " mesh=mesh, in_specs=P('d'), out_specs=P('d'),"
+            " check_vma=False);"
             "got = np.asarray(f(x));"
             "want = np.asarray(x.sum(0));"
             "err = np.abs(got - want).max() / max(np.abs(want).max(), 1);"

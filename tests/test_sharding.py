@@ -12,15 +12,8 @@ from repro.distributed.sharding import (RULES, resolve_spec, param_pspecs,
                                         ResolveReport, _cache_leaf_pspec,
                                         cache_shardings)
 
-def _abstract_mesh(shape, names):
-    try:                        # jax >= 0.4.38: (axis_sizes, axis_names)
-        return AbstractMesh(shape, names)
-    except TypeError:           # jax 0.4.37: ((name, size), ...) pairs
-        return AbstractMesh(tuple(zip(names, shape)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestResolver:
