@@ -106,8 +106,7 @@ def main() -> int:
                    fig08_scalability, fig09_sync, fig10_abort_skew,
                    fig12_tpcc, fig13_batch, fig14_recovery, fig15_adaptive,
                    fig16_brook, fig17_serving, fig18_waitprofile,
-                   fig19_hotspot, kernel_bench, profile_step,
-                   roofline_table)
+                   fig19_hotspot, kernel_bench, roofline_table)
     from repro.obs import compile_log
     compile_log.enable_telemetry()
     modules = {
@@ -120,7 +119,7 @@ def main() -> int:
         "fig18": fig18_waitprofile, "fig19": fig19_hotspot,
         "compaction": compaction_bench,
         "kernels": kernel_bench, "roofline": roofline_table,
-        "profile": profile_step, "analysis": analysis_gate,
+        "analysis": analysis_gate,
     }
     if args.only:
         modules = {args.only: modules[args.only]}

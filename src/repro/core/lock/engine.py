@@ -65,6 +65,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from .costs import CostModel, ProtocolParams, protocol_params
 from .workload import (WorkloadSpec, DynWorkload, dyn_workload, gen_txn_dyn,
@@ -121,12 +122,45 @@ CA_WAIT, CA_GRANTS, CA_TIMEOUTS, CA_VICTIMS, CA_QSUM, CA_QMAX = range(N_CA)
 CA_NAMES = ("wait_ticks", "grants", "timeouts", "victims",
             "queue_sum", "queue_max")
 
-# --- stage ablation (profiler seam, DESIGN.md §12) -----------------------
+# --- stage scopes (DESIGN.md §12.1) --------------------------------------
+# Every instruction of an engine program carries one of these
+# ``jax.named_scope`` labels in its HLO ``op_name`` metadata, so device time
+# read off a trace (keyed by instruction name) attributes to stages through
+# ``repro.obs.prof.op_stages``. The step's scopes do not nest;
+# ``seg_snapshot`` wraps a ``_derive`` call, and the outermost scope wins.
+# Labels are metadata only: they add no operation and change no result.
+STAGE_SCOPES = (
+    # the loop body, in step order
+    "stage_derive",          # _derive: T*L -> R segment reductions
+    "stage_mark_aborts",     # 1a/1c/1d timeouts, proactive, cascades; 2
+    "stage_deadlock_walk",   # 1b waits-for cycle walk
+    "stage_grant_apply",     # 4a grant costs, counters, updating flags
+    "stage_ticket_grant",    # 4a grant-rule mask
+    "stage_group_lock",      # 4a group leader/member bookkeeping
+    "stage_commit_order",    # 4b commit eligibility and phase moves
+    "stage_group_commit",    # 4b group-commit sync windows
+    "stage_rollback_turn",   # 4c RBWAIT -> RBACK
+    "stage_advance",         # 5 dt, time and work advance
+    "stage_tick_charge",     # 5 TickBreakdown and per-record scatters
+    "stage_complete",        # 6 completions: apply, commit, rollback
+    "stage_txn_start",       # 7 halts, arrival gate, new-txn selection
+    "stage_gen_txn",         # 7 gen_txn_dyn key generation
+    "stage_op_begin",        # 8 direct exec, tickets, next-ticket counts
+    "stage_ticket_assign",   # 8 FIFO same-tick ranking (argsort)
+    "stage_hotspot_detect",  # 9 hot-row promotion / demotion
+    # loop control, outside the body
+    "loop_setup",            # loop-invariant values hoisted before it
+    "loop_cond",             # the while condition
+    # after the loop (segmented entry points)
+    "seg_snapshot",          # boundary telemetry (SegSnapshot)
+)
+
+# --- stage ablation (control seam, DESIGN.md §12) ------------------------
 # ``_make_step_events(..., ablate={stage})`` replaces one named stage's
 # compute with a shape-correct stand-in so XLA dead-code-eliminates the
-# stage from the compiled program; the per-stage step profiler
-# (``repro.obs.prof``) attributes per-iteration wall cost by differencing
-# against the full step. Each ablation is the exact identity on the step
+# stage from the compiled program; the benchmark's control
+# (``bench/control.py``) builds the program with a stage switched off
+# through it. Each ablation is the exact identity on the step
 # whenever the stage's work is trivially absent (protocol flag off,
 # read-only workload, txn_len 1 — asserted bit-exactly in
 # tests/test_prof.py), and ``ablate=frozenset()`` (every production entry
@@ -363,6 +397,7 @@ class Derived(NamedTuple):
     napp: jnp.ndarray         # (T,) applied op count per thread
 
 
+@jax.named_scope("stage_derive")
 def _derive(stat: StaticShape, dp: DynParams, th: Threads,
             rows: Rows, ablate: frozenset = frozenset()) -> Derived:
     R = stat.n_rows
@@ -465,8 +500,8 @@ def _make_step_events(stat: StaticShape, dp: DynParams, until=None,
     every parameter in ``dp`` is traced, so protocol branches are computed
     unconditionally and masked — the price of one program for all configs.
 
-    ``ablate`` (static, profiler-only — see :data:`PROF_STAGES` and
-    ``repro.obs.prof``) names stages whose compute is replaced by a
+    ``ablate`` (static, control seam only — see :data:`PROF_STAGES` and
+    ``repro.obs.prof.STAGE_NOOPS``) names stages whose compute is replaced by a
     shape-correct stand-in so XLA eliminates them from the program. The
     default empty set takes the exact code path that existed before the
     seam — production entry points never pass it.
@@ -506,22 +541,22 @@ def _make_step_events(stat: StaticShape, dp: DynParams, until=None,
 
     def step(s: SimState) -> tuple[SimState, StepEvents]:
         th, rows, g = s
-        with jax.named_scope("stage_derive"):
-            d = _derive(stat, dp, th, rows, ablate)
+        d = _derive(stat, dp, th, rows, ablate)
         now = g.now
 
-        cur_key = cur(th.keys, th.op)
-        cur_tkt = cur(th.ticket, th.op)
-        in_wait = th.phase == WAIT
+        with jax.named_scope("stage_mark_aborts"):
+            cur_key = cur(th.keys, th.op)
+            cur_tkt = cur(th.ticket, th.op)
+            in_wait = th.phase == WAIT
 
-        # ------------------------------------------------ 1. mark aborts
-        forced = th.forced
-        # 1a. wait timeout (wait_timeout <= 0 disables both timeouts)
-        to = in_wait & ((now - th.wstart) >= dp.wait_timeout)
-        to |= (th.phase == CWAIT) & (
-            (now - th.wstart) >= dp.commit_wait_timeout)
-        to_fire = to & (dp.wait_timeout > 0)
-        forced = forced | to_fire
+            # ------------------------------------------------ 1. mark aborts
+            forced = th.forced
+            # 1a. wait timeout (wait_timeout <= 0 disables both timeouts)
+            to = in_wait & ((now - th.wstart) >= dp.wait_timeout)
+            to |= (th.phase == CWAIT) & (
+                (now - th.wstart) >= dp.commit_wait_timeout)
+            to_fire = to & (dp.wait_timeout > 0)
+            forced = forced | to_fire
         # 1b. deadlock detection (waits-for cycle walk, up to 8 hops),
         # 2PL-style protocols. One victim per cycle: its max thread id.
         # lax.cond so single-config runs of detection-free protocols skip
@@ -551,92 +586,97 @@ def _make_step_events(stat: StaticShape, dp: DynParams, until=None,
                 victim = lax.cond(dp.has_detection, _walk_cycle,
                                   lambda op: jnp.zeros_like(op[0]),
                                   (in_wait, th.phase, d.holder[cur_key]))
-        forced = forced | victim
-        # 1c. proactive hot+non-hot rollback (§4.5)
-        hrow = d.hotof
-        hold = d.holder[cur_key]
-        hold_ok = hold >= 0
-        hold_i = jnp.where(hold_ok, hold, 0)
-        pro = (in_wait & (hrow >= 0) & hold_ok
-               & ~rows.hot[cur_key]
-               & (d.hotof[hold_i] == hrow) & (hold != tids))
-        forced = forced | (pro & dp.proactive_abort)
-        # 1d. cascade propagation: any applied early ticket >= casc[key]
-        casc_at = rows.casc[th.keys]                          # (T, L)
-        hit = (th.applied & th.early & (th.ticket >= 0)
-               & (th.ticket >= casc_at))
-        forced = forced | hit.any(axis=1)
-        # threads that cannot abort anymore (committing) stay
-        forced = forced & (th.phase != COMMIT) & (th.phase != HALT)
+        with jax.named_scope("stage_mark_aborts"):
+            forced = forced | victim
+            # 1c. proactive hot+non-hot rollback (§4.5)
+            hrow = d.hotof
+            hold = d.holder[cur_key]
+            hold_ok = hold >= 0
+            hold_i = jnp.where(hold_ok, hold, 0)
+            pro = (in_wait & (hrow >= 0) & hold_ok
+                   & ~rows.hot[cur_key]
+                   & (d.hotof[hold_i] == hrow) & (hold != tids))
+            forced = forced | (pro & dp.proactive_abort)
+            # 1d. cascade propagation: any applied early ticket >= casc[key]
+            casc_at = rows.casc[th.keys]                          # (T, L)
+            hit = (th.applied & th.early & (th.ticket >= 0)
+                   & (th.ticket >= casc_at))
+            forced = forced | hit.any(axis=1)
+            # threads that cannot abort anymore (committing) stay
+            forced = forced & (th.phase != COMMIT) & (th.phase != HALT)
 
-        # forced threads with applied early tickets keep cascades open
-        # (idempotent marking — covers voluntary commit-point aborts too,
-        # which become forced outside this stage)
-        casc_src = (th.applied & th.early & (th.ticket >= 0)
-                    & forced[:, None])
-        casc_min = _seg_min(th.ticket, th.keys, R, casc_src)
-        casc = jnp.minimum(rows.casc, casc_min)
-        # clear finished cascades: no applied ticket at/above casc remains
-        casc = jnp.where((casc < INF) & (d.top < casc), INF, casc)
-        rows = rows._replace(casc=casc)
-        th = th._replace(forced=forced)
+            # forced threads with applied early tickets keep cascades open
+            # (idempotent marking — covers voluntary commit-point aborts too,
+            # which become forced outside this stage)
+            casc_src = (th.applied & th.early & (th.ticket >= 0)
+                        & forced[:, None])
+            casc_min = _seg_min(th.ticket, th.keys, R, casc_src)
+            casc = jnp.minimum(rows.casc, casc_min)
+            # clear finished cascades: no applied ticket at/above casc remains
+            casc = jnp.where((casc < INF) & (d.top < casc), INF, casc)
+            rows = rows._replace(casc=casc)
+            th = th._replace(forced=forced)
 
-        # ------------------------------------------------ 2. divert to RBWAIT
-        # forced threads in WAIT/CWAIT park for their cascade turn.
-        parkable = forced & ((th.phase == WAIT) | (th.phase == CWAIT))
-        phase = jnp.where(parkable, RBWAIT, th.phase)
-        th = th._replace(phase=phase,
-                         wstart=jnp.where(parkable, now, th.wstart))
+            # -------------------------------------------- 2. divert to RBWAIT
+            # forced threads in WAIT/CWAIT park for their cascade turn.
+            parkable = forced & ((th.phase == WAIT) | (th.phase == CWAIT))
+            phase = jnp.where(parkable, RBWAIT, th.phase)
+            th = th._replace(phase=phase,
+                             wstart=jnp.where(parkable, now, th.wstart))
 
-        # ------------------------------------------------ 4. grants
-        d2 = d  # row aggregates from top of iteration (conservative)
-        # 4a. WAIT -> EXEC
-        is_w = (th.phase == WAIT) & ~th.forced
-        key_w = cur_key
-        hot_w = rows.hot[key_w]
+        with jax.named_scope("stage_grant_apply"):
+            # ------------------------------------------------ 4. grants
+            d2 = d  # row aggregates from top of iteration (conservative)
+            # 4a. WAIT -> EXEC
+            is_w = (th.phase == WAIT) & ~th.forced
+            key_w = cur_key
+            hot_w = rows.hot[key_w]
         with jax.named_scope("stage_ticket_grant"):
             grantable = (is_w & (cur_tkt == d2.us[key_w])
                          & ~rows.updating[key_w]
                          & (rows.casc[key_w] == INF))
-        if "ticket_grant" in ablate:
-            # stand-in: nothing grants (identity on read-only workloads,
-            # where no thread ever takes a ticket or enters WAIT)
-            grantable = jnp.zeros_like(grantable)
-        # group locking: leader/follower bookkeeping
-        open_leader = rows.gleader[key_w]
-        is_leader_grant = (grantable & hot_w & (open_leader == NOTK)
-                           & dp.group_lock)
-        is_member_grant = (grantable & hot_w & (open_leader != NOTK)
-                           & dp.group_lock)
+        with jax.named_scope("stage_grant_apply"):
+            if "ticket_grant" in ablate:
+                # stand-in: nothing grants (identity on read-only workloads,
+                # where no thread ever takes a ticket or enters WAIT)
+                grantable = jnp.zeros_like(grantable)
+            # group locking: leader/follower bookkeeping
+            open_leader = rows.gleader[key_w]
+            is_leader_grant = (grantable & hot_w & (open_leader == NOTK)
+                               & dp.group_lock)
+            is_member_grant = (grantable & hot_w & (open_leader != NOTK)
+                               & dp.group_lock)
 
-        qlen = d2.n_wait[key_w].astype(F32)
-        dd = jnp.where(dp.has_detection,
-                       (dp.dd_coeff * qlen).astype(I32), 0)
-        hotq = hot_w & dp.hot_queue
-        overhead = jnp.where(
-            hotq,
-            jnp.where(is_leader_grant | ~dp.group_lock,
-                      dp.lock_base, dp.grant_cost),
-            dp.lock_base + dd)
-        work_g = overhead + dp.op_exec
+            qlen = d2.n_wait[key_w].astype(F32)
+            dd = jnp.where(dp.has_detection,
+                           (dp.dd_coeff * qlen).astype(I32), 0)
+            hotq = hot_w & dp.hot_queue
+            overhead = jnp.where(
+                hotq,
+                jnp.where(is_leader_grant | ~dp.group_lock,
+                          dp.lock_base, dp.grant_cost),
+                dp.lock_base + dd)
+            work_g = overhead + dp.op_exec
 
-        th = th._replace(
-            phase=jnp.where(grantable, EXEC, th.phase),
-            work=jnp.where(grantable, work_g, th.work),
-            # detection ticks inside this grant's work (tick attribution)
-            detleft=jnp.where(grantable, jnp.where(hotq, 0, dd),
-                              th.detleft))
-        g = g._replace(
-            wait_ticks=g.wait_ticks
-            + jnp.sum(jnp.where(grantable, (now - th.wstart), 0)).astype(F32),
-            lock_ops=g.lock_ops
-            + jnp.sum(jnp.where(grantable & (~hotq | is_leader_grant), 1, 0)),
-            dd_ticks=g.dd_ticks
-            + jnp.sum(jnp.where(grantable & ~hotq, dd, 0)))
+            th = th._replace(
+                phase=jnp.where(grantable, EXEC, th.phase),
+                work=jnp.where(grantable, work_g, th.work),
+                # detection ticks inside this grant's work (tick attribution)
+                detleft=jnp.where(grantable, jnp.where(hotq, 0, dd),
+                                  th.detleft))
+            g = g._replace(
+                wait_ticks=g.wait_ticks
+                + jnp.sum(jnp.where(grantable, (now - th.wstart), 0)
+                          ).astype(F32),
+                lock_ops=g.lock_ops
+                + jnp.sum(jnp.where(grantable & (~hotq | is_leader_grant),
+                                    1, 0)),
+                dd_ticks=g.dd_ticks
+                + jnp.sum(jnp.where(grantable & ~hotq, dd, 0)))
 
-        upd_new = _seg_max(jnp.ones_like(key_w), key_w, R,
-                           grantable) > 0
-        rows = rows._replace(updating=rows.updating | upd_new)
+            upd_new = _seg_max(jnp.ones_like(key_w), key_w, R,
+                               grantable) > 0
+            rows = rows._replace(updating=rows.updating | upd_new)
 
         # group bookkeeping: without group locking gleader stays NOTK and
         # gcount 0, so the off branch is the identity (runtime-skipped for
@@ -662,26 +702,28 @@ def _make_step_events(stat: StaticShape, dp: DynParams, until=None,
                                   (rows.gleader, rows.gcount))
         rows = rows._replace(gleader=gl, gcount=gc)
 
-        # 4b. CWAIT -> COMMIT (commit order on early rows; leader hold)
-        is_cw = (th.phase == CWAIT) & ~th.forced
-        live = th.ticket >= 0
-        cc_at = d2.cc[th.keys]
-        # released slots are OUT of the commit order entirely (brook):
-        # the releaser itself must not wait for cc to reach a ticket that
-        # cc now skips — only early-but-unreleased slots order commits.
-        order_ok = jnp.where(live & th.applied & th.early & ~th.released,
-                             cc_at == th.ticket, True).all(axis=1)
-        no_casc = jnp.where(live, rows.casc[th.keys] == INF, True).all(axis=1)
-        lead_open = (jnp.where(live & th.applied & th.early,
-                               rows.gleader[th.keys] == th.ticket,
-                               False).any(axis=1)
-                     & dp.group_lock)
-        can_commit = is_cw & order_ok & no_casc & ~lead_open
-        # injected aborts divert to rollback at the commit point
-        vol = can_commit & th.willab
-        can_commit = can_commit & ~th.willab
+        with jax.named_scope("stage_commit_order"):
+            # 4b. CWAIT -> COMMIT (commit order on early rows; leader hold)
+            is_cw = (th.phase == CWAIT) & ~th.forced
+            live = th.ticket >= 0
+            cc_at = d2.cc[th.keys]
+            # released slots are OUT of the commit order entirely (brook):
+            # the releaser itself must not wait for cc to reach a ticket that
+            # cc now skips — only early-but-unreleased slots order commits.
+            order_ok = jnp.where(live & th.applied & th.early & ~th.released,
+                                 cc_at == th.ticket, True).all(axis=1)
+            no_casc = jnp.where(live, rows.casc[th.keys] == INF,
+                                True).all(axis=1)
+            lead_open = (jnp.where(live & th.applied & th.early,
+                                   rows.gleader[th.keys] == th.ticket,
+                                   False).any(axis=1)
+                         & dp.group_lock)
+            can_commit = is_cw & order_ok & no_casc & ~lead_open
+            # injected aborts divert to rollback at the commit point
+            vol = can_commit & th.willab
+            can_commit = can_commit & ~th.willab
 
-        base_cost = dp.commit_base + dp.sync_lat
+            base_cost = dp.commit_base + dp.sync_lat
 
         # Group commit (Fig. 5c): while a hot row's sync window is in
         # flight, arriving commits of that row join it (binlog group
@@ -716,69 +758,72 @@ def _make_step_events(stat: StaticShape, dp: DynParams, until=None,
                                           & (dp.sync_lat > 0),
                                           _gcommit_on, _gcommit_off,
                                           (rows.batch_end, rows.batch_n))
-        rows = rows._replace(batch_end=nbe, batch_n=nbn)
-        th = th._replace(
-            phase=jnp.where(can_commit, COMMIT,
-                            jnp.where(vol, RBWAIT, th.phase)),
-            work=jnp.where(can_commit, cost, th.work),
-            wstart=jnp.where(vol, now, th.wstart),
-            committing=th.committing | (can_commit[:, None] & th.applied),
-            forced=th.forced | vol,
-            vabort=th.vabort | vol)
+        with jax.named_scope("stage_commit_order"):
+            rows = rows._replace(batch_end=nbe, batch_n=nbn)
+            th = th._replace(
+                phase=jnp.where(can_commit, COMMIT,
+                                jnp.where(vol, RBWAIT, th.phase)),
+                work=jnp.where(can_commit, cost, th.work),
+                wstart=jnp.where(vol, now, th.wstart),
+                committing=th.committing | (can_commit[:, None] & th.applied),
+                forced=th.forced | vol,
+                vabort=th.vabort | vol)
 
-        # ------------------------------------------------ 4c. RBWAIT->RBACK
-        # (after 4b so voluntary commit-point aborts start their rollback
-        # in the same iteration — otherwise dt can jump to a timeout.)
-        # my turn iff for my early applied rows the top applied ticket is
-        # mine (reverse update order, Alg. 3). No early applied rows => go.
-        ea = th.applied & th.early & (th.ticket >= 0)
-        top_at = d.top[th.keys]
-        my_turn = jnp.where(ea, top_at == th.ticket, True).all(axis=1)
-        # multi-row cascade cycles (paper §6.5's excluded case) break via
-        # an out-of-order rollback after rb_turn_timeout
-        my_turn = my_turn | ((now - th.wstart) >= dp.rb_turn_timeout)
-        start_rb = (th.phase == RBWAIT) & my_turn
-        rb_work = dp.rb_base + dp.rb_per_op * d.napp
-        th = th._replace(
-            phase=jnp.where(start_rb, RBACK, th.phase),
-            work=jnp.where(start_rb, rb_work, th.work))
+        with jax.named_scope("stage_rollback_turn"):
+            # -------------------------------------------- 4c. RBWAIT->RBACK
+            # (after 4b so voluntary commit-point aborts start their rollback
+            # in the same iteration — otherwise dt can jump to a timeout.)
+            # my turn iff for my early applied rows the top applied ticket is
+            # mine (reverse update order, Alg. 3). No early applied rows => go.
+            ea = th.applied & th.early & (th.ticket >= 0)
+            top_at = d.top[th.keys]
+            my_turn = jnp.where(ea, top_at == th.ticket, True).all(axis=1)
+            # multi-row cascade cycles (paper §6.5's excluded case) break via
+            # an out-of-order rollback after rb_turn_timeout
+            my_turn = my_turn | ((now - th.wstart) >= dp.rb_turn_timeout)
+            start_rb = (th.phase == RBWAIT) & my_turn
+            rb_work = dp.rb_base + dp.rb_per_op * d.napp
+            th = th._replace(
+                phase=jnp.where(start_rb, RBACK, th.phase),
+                work=jnp.where(start_rb, rb_work, th.work))
 
-        # ------------------------------------------------ 5. dt & advance
-        paying = ((th.phase == EXEC) | (th.phase == COMMIT)
-                  | (th.phase == RBACK) | (th.phase == BACKOFF)
-                  | (th.phase == ARRIVE))
-        starting = th.phase == START
-        dt_pay = jnp.where(paying, th.work, INF).min()
-        texp = jnp.where((in_wait | (th.phase == CWAIT))
-                         & (dp.wait_timeout > 0),
-                         th.wstart + dp.wait_timeout - now, INF).min()
-        rb_exp = jnp.where(th.phase == RBWAIT,
-                           th.wstart + dp.rb_turn_timeout - now, INF).min()
-        texp = jnp.minimum(texp, jnp.maximum(rb_exp, 1))
-        dt = jnp.minimum(dt_pay, jnp.maximum(texp, 1))
-        dt = jnp.where(starting.any(), 0, dt)       # starts are instant
-        # idle windows (nothing paying) stop at the segment boundary;
-        # busy steps keep single-shot event timing (see docstring above)
-        cap = jnp.where(dt_pay == INF, idle_stop, stop_time)
-        dt = jnp.clip(dt, 0, jnp.maximum(cap - now, 1))
-        now = now + dt
-        work = jnp.where(paying, th.work - dt, th.work)
-        th = th._replace(work=work)
+        with jax.named_scope("stage_advance"):
+            # ------------------------------------------------ 5. dt & advance
+            paying = ((th.phase == EXEC) | (th.phase == COMMIT)
+                      | (th.phase == RBACK) | (th.phase == BACKOFF)
+                      | (th.phase == ARRIVE))
+            starting = th.phase == START
+            dt_pay = jnp.where(paying, th.work, INF).min()
+            texp = jnp.where((in_wait | (th.phase == CWAIT))
+                             & (dp.wait_timeout > 0),
+                             th.wstart + dp.wait_timeout - now, INF).min()
+            rb_exp = jnp.where(th.phase == RBWAIT,
+                               th.wstart + dp.rb_turn_timeout - now, INF).min()
+            texp = jnp.minimum(texp, jnp.maximum(rb_exp, 1))
+            dt = jnp.minimum(dt_pay, jnp.maximum(texp, 1))
+            dt = jnp.where(starting.any(), 0, dt)       # starts are instant
+            # idle windows (nothing paying) stop at the segment boundary;
+            # busy steps keep single-shot event timing (see docstring above)
+            cap = jnp.where(dt_pay == INF, idle_stop, stop_time)
+            dt = jnp.clip(dt, 0, jnp.maximum(cap - now, 1))
+            now = now + dt
+            work = jnp.where(paying, th.work - dt, th.work)
+            th = th._replace(work=work)
 
-        n_busy = ((th.phase == EXEC) | (th.phase == COMMIT)
-                  | (th.phase == RBACK)).sum().astype(F32)
-        g = g._replace(now=now, iters=g.iters + 1,
-                       busy_ticks=g.busy_ticks + n_busy * dt.astype(F32))
+            n_busy = ((th.phase == EXEC) | (th.phase == COMMIT)
+                      | (th.phase == RBACK)).sum().astype(F32)
+            g = g._replace(now=now, iters=g.iters + 1,
+                           busy_ticks=g.busy_ticks + n_busy * dt.astype(F32))
 
-        # --- tick attribution (obs, DESIGN.md §11): charge dt to exactly
-        # one TickBreakdown bin per thread. Branch 1 ("hot") when the
-        # thread is engaged on a promoted-hot row; EXEC pays its pending
-        # detection ticks (detleft, set at grant) before exec proper.
-        # Each iteration contributes exactly T*dt across bins, so
-        # sum(g.tb) == T * g.now holds at every observation point.
-        is_ex = th.phase == EXEC
-        ddpay = jnp.where(is_ex, jnp.minimum(th.detleft, dt), 0)
-        th = th._replace(detleft=th.detleft - ddpay)
+            # --- tick attribution (obs, DESIGN.md §11): charge dt to exactly
+            # one TickBreakdown bin per thread. Branch 1 ("hot") when the
+            # thread is engaged on a promoted-hot row; EXEC pays its pending
+            # detection ticks (detleft, set at grant) before exec proper.
+            # Each iteration contributes exactly T*dt across bins, so
+            # sum(g.tb) == T * g.now holds at every observation point.
+            is_ex = th.phase == EXEC
+            ddpay = jnp.where(is_ex, jnp.minimum(th.detleft, dt), 0)
+            th = th._replace(detleft=th.detleft - ddpay)
         if "tick_charge" in ablate:
             pass    # stand-in: tb untouched — every other leaf (incl.
             #         detleft above) evolves bit-exactly on ANY config
@@ -819,111 +864,113 @@ def _make_step_events(stat: StaticShape, dp: DynParams, until=None,
                 g = g._replace(ca=lax.cond(dp.attrib, _ca_on,
                                            lambda ca: ca, g.ca))
 
-        done = paying & (work <= 0)
+        with jax.named_scope("stage_complete"):
+            done = paying & (work <= 0)
 
-        # ------------------------------------------------ 6. completions
-        # 6a. EXEC done: apply the write, advance op
-        e_done = done & (th.phase == EXEC)
-        wr_now = cur(th.iswr, th.op) & e_done
-        eff_wr = wr_now & ~cur(th.dup, th.op)
-        rows = rows._replace(
-            applied_val=rows.applied_val.at[cur_key].add(
-                jnp.where(eff_wr, 1, 0), mode="drop"),
-            updating=rows.updating & ~(
-                _seg_max(jnp.ones_like(cur_key), cur_key, R, eff_wr) > 0))
-        opc = jnp.clip(th.op, 0, L - 1)
-        applied = th.applied.at[tids, opc].set(
-            jnp.where(eff_wr, True, cur(th.applied, th.op)))
-        # freeze the release semantics that were in force when we applied
-        early_now = dp.early_all | (dp.early_release & rows.hot[cur_key])
-        early = th.early.at[tids, opc].set(
-            jnp.where(eff_wr, early_now, cur(th.early, th.op)))
-        th = th._replace(applied=applied, early=early)
-        # Brook-2PL per-op release (chop.py): when an op completes at its
-        # key's LAST use, the key's ticket retires — `early` opens the
-        # grant path and `released` drops the commit-order dependency, so
-        # successors lock, update, AND commit ahead of the releaser.
-        # Gated on ~willab: a txn that will abort at its commit point
-        # keeps strict-2PL holds, so no dirty read can ever involve an
-        # aborting brook txn — deadlock-free AND cascade-free. If a
-        # released txn is nonetheless forced (brook_guard timeouts after
-        # a governed switch-in), its early slots open a cascade on the
-        # row, which freezes further grants AND commits there (no_casc)
-        # until the dependents drain via their own timeouts; successor
-        # writes are commutative increments, so the counter invariant
-        # survives the out-of-order revert (same argument as
-        # rb_turn_timeout in costs.py).
-        rel_now = (e_done & cur(th.lastu, th.op) & dp.per_op_release
-                   & ~th.forced & ~th.willab)
-        rel_slot = ((th.keys == cur_key[:, None]) & (th.ticket >= 0)
-                    & rel_now[:, None])
-        th = th._replace(
-            released=th.released | rel_slot,
-            early=th.early | (rel_slot & th.applied))
-        nop = th.op + jnp.where(e_done, 1, 0)
-        txn_done = e_done & (nop >= th.nops)
-        th = th._replace(op=nop)
-        # forced threads stop making progress after their op completes
-        to_park = e_done & th.forced
-        th = th._replace(phase=jnp.where(to_park, RBWAIT, th.phase))
-        e_done = e_done & ~to_park
-        txn_done = txn_done & ~to_park
-        th = th._replace(
-            phase=jnp.where(txn_done, CWAIT, th.phase),
-            wstart=jnp.where(txn_done, now, th.wstart))
-        next_op = e_done & ~txn_done
+            # ------------------------------------------------ 6. completions
+            # 6a. EXEC done: apply the write, advance op
+            e_done = done & (th.phase == EXEC)
+            wr_now = cur(th.iswr, th.op) & e_done
+            eff_wr = wr_now & ~cur(th.dup, th.op)
+            rows = rows._replace(
+                applied_val=rows.applied_val.at[cur_key].add(
+                    jnp.where(eff_wr, 1, 0), mode="drop"),
+                updating=rows.updating & ~(
+                    _seg_max(jnp.ones_like(cur_key), cur_key, R, eff_wr) > 0))
+            opc = jnp.clip(th.op, 0, L - 1)
+            applied = th.applied.at[tids, opc].set(
+                jnp.where(eff_wr, True, cur(th.applied, th.op)))
+            # freeze the release semantics that were in force when we applied
+            early_now = dp.early_all | (dp.early_release & rows.hot[cur_key])
+            early = th.early.at[tids, opc].set(
+                jnp.where(eff_wr, early_now, cur(th.early, th.op)))
+            th = th._replace(applied=applied, early=early)
+            # Brook-2PL per-op release (chop.py): when an op completes at its
+            # key's LAST use, the key's ticket retires — `early` opens the
+            # grant path and `released` drops the commit-order dependency, so
+            # successors lock, update, AND commit ahead of the releaser.
+            # Gated on ~willab: a txn that will abort at its commit point
+            # keeps strict-2PL holds, so no dirty read can ever involve an
+            # aborting brook txn — deadlock-free AND cascade-free. If a
+            # released txn is nonetheless forced (brook_guard timeouts after
+            # a governed switch-in), its early slots open a cascade on the
+            # row, which freezes further grants AND commits there (no_casc)
+            # until the dependents drain via their own timeouts; successor
+            # writes are commutative increments, so the counter invariant
+            # survives the out-of-order revert (same argument as
+            # rb_turn_timeout in costs.py).
+            rel_now = (e_done & cur(th.lastu, th.op) & dp.per_op_release
+                       & ~th.forced & ~th.willab)
+            rel_slot = ((th.keys == cur_key[:, None]) & (th.ticket >= 0)
+                        & rel_now[:, None])
+            th = th._replace(
+                released=th.released | rel_slot,
+                early=th.early | (rel_slot & th.applied))
+            nop = th.op + jnp.where(e_done, 1, 0)
+            txn_done = e_done & (nop >= th.nops)
+            th = th._replace(op=nop)
+            # forced threads stop making progress after their op completes
+            to_park = e_done & th.forced
+            th = th._replace(phase=jnp.where(to_park, RBWAIT, th.phase))
+            e_done = e_done & ~to_park
+            txn_done = txn_done & ~to_park
+            th = th._replace(
+                phase=jnp.where(txn_done, CWAIT, th.phase),
+                wstart=jnp.where(txn_done, now, th.wstart))
+            next_op = e_done & ~txn_done
 
-        # 6b. COMMIT done: release everything, count, next txn
-        c_done = done & (th.phase == COMMIT)
-        rel = th.ticket >= 0
-        committed_w = rel & th.applied & c_done[:, None]
-        rows = rows._replace(
-            committed_val=rows.committed_val.at[th.keys].add(
-                jnp.where(committed_w, 1, 0), mode="drop"))
-        lat = now - th.tstart
-        g = g._replace(
-            commits=g.commits + c_done.sum(),
-            lat_sum=g.lat_sum + jnp.where(c_done, lat, 0).sum().astype(F32),
-            hist=g.hist.at[_hist_bucket(lat)].add(
-                jnp.where(c_done, 1, 0), mode="drop"))
+            # 6b. COMMIT done: release everything, count, next txn
+            c_done = done & (th.phase == COMMIT)
+            rel = th.ticket >= 0
+            committed_w = rel & th.applied & c_done[:, None]
+            rows = rows._replace(
+                committed_val=rows.committed_val.at[th.keys].add(
+                    jnp.where(committed_w, 1, 0), mode="drop"))
+            lat = now - th.tstart
+            g = g._replace(
+                commits=g.commits + c_done.sum(),
+                lat_sum=g.lat_sum
+                + jnp.where(c_done, lat, 0).sum().astype(F32),
+                hist=g.hist.at[_hist_bucket(lat)].add(
+                    jnp.where(c_done, 1, 0), mode="drop"))
 
-        # 6c. RBACK done: revert applied writes, release tickets
-        r_done = done & (th.phase == RBACK)
-        reverted = rel & th.applied & r_done[:, None]
-        rows = rows._replace(
-            applied_val=rows.applied_val.at[th.keys].add(
-                jnp.where(reverted, -1, 0), mode="drop"))
-        g = g._replace(
-            user_aborts=g.user_aborts + (r_done & th.vabort).sum(),
-            forced_aborts=g.forced_aborts + (r_done & ~th.vabort).sum())
+            # 6c. RBACK done: revert applied writes, release tickets
+            r_done = done & (th.phase == RBACK)
+            reverted = rel & th.applied & r_done[:, None]
+            rows = rows._replace(
+                applied_val=rows.applied_val.at[th.keys].add(
+                    jnp.where(reverted, -1, 0), mode="drop"))
+            g = g._replace(
+                user_aborts=g.user_aborts + (r_done & th.vabort).sum(),
+                forced_aborts=g.forced_aborts + (r_done & ~th.vabort).sum())
 
-        clear = (c_done | r_done)[:, None]
-        th = th._replace(
-            ticket=jnp.where(clear, NOTK, th.ticket),
-            applied=jnp.where(clear, False, th.applied),
-            early=jnp.where(clear, False, th.early),
-            committing=jnp.where(clear, False, th.committing),
-            released=jnp.where(clear, False, th.released))
+            clear = (c_done | r_done)[:, None]
+            th = th._replace(
+                ticket=jnp.where(clear, NOTK, th.ticket),
+                applied=jnp.where(clear, False, th.applied),
+                early=jnp.where(clear, False, th.early),
+                committing=jnp.where(clear, False, th.committing),
+                released=jnp.where(clear, False, th.released))
 
-        # 6d. BACKOFF done -> START; COMMIT/RBACK -> next
-        # backoff is jittered per (thread, txn) to break retry lockstep
-        # (identical-key retries re-forming the same deadlock forever)
-        b_done = done & (th.phase == BACKOFF)
-        jitter = ((tids * I32(40503) + th.txn * I32(9973)) % I32(4) + 1)
-        th = th._replace(
-            phase=jnp.where(c_done | b_done, START,
-                            jnp.where(r_done, BACKOFF, th.phase)),
-            work=jnp.where(r_done, dp.backoff * jitter, th.work),
-            txn=th.txn + jnp.where(c_done | (r_done & th.vabort), 1, 0),
-            retry=jnp.where(r_done & ~th.vabort, True,
-                            jnp.where(c_done, False, th.retry)),
-            forced=jnp.where(r_done, False, th.forced),
-            vabort=jnp.where(r_done, False, th.vabort),
-            op=jnp.where(c_done | r_done, 0, nop))
+            # 6d. BACKOFF done -> START; COMMIT/RBACK -> next
+            # backoff is jittered per (thread, txn) to break retry lockstep
+            # (identical-key retries re-forming the same deadlock forever)
+            b_done = done & (th.phase == BACKOFF)
+            jitter = ((tids * I32(40503) + th.txn * I32(9973)) % I32(4) + 1)
+            th = th._replace(
+                phase=jnp.where(c_done | b_done, START,
+                                jnp.where(r_done, BACKOFF, th.phase)),
+                work=jnp.where(r_done, dp.backoff * jitter, th.work),
+                txn=th.txn + jnp.where(c_done | (r_done & th.vabort), 1, 0),
+                retry=jnp.where(r_done & ~th.vabort, True,
+                                jnp.where(c_done, False, th.retry)),
+                forced=jnp.where(r_done, False, th.forced),
+                vabort=jnp.where(r_done, False, th.vabort),
+                op=jnp.where(c_done | r_done, 0, nop))
 
-        # 6e. ARRIVE done -> START
-        a_done = done & (th.phase == ARRIVE)
-        th = th._replace(phase=jnp.where(a_done, START, th.phase))
+            # 6e. ARRIVE done -> START
+            a_done = done & (th.phase == ARRIVE)
+            th = th._replace(phase=jnp.where(a_done, START, th.phase))
 
         # ------------------------------------------------ 7. START new txns
         # A thread halts at the horizon OR when its transaction quota is
@@ -932,54 +979,57 @@ def _make_step_events(stat: StaticShape, dp: DynParams, until=None,
         # the instant its last credited txn commits (6d set START this
         # same iteration) — the serving layer revives it with new credits
         # at the next segment boundary.
-        st = th.phase == START
-        past = (now >= dp.horizon) | (th.txn >= dp.txn_cap)
-        th = th._replace(phase=jnp.where(st & past, HALT, th.phase))
-        st = st & ~past
-        # fixed-TPS open loop: arrival_rate <= 0 means closed loop (no gate).
-        # n_active (not the padded T) sets the per-thread arrival interval.
-        rate_on = dp.arrival_rate > 0
-        interval = jnp.maximum(
-            (dp.n_active.astype(F32)
-             / jnp.where(rate_on, dp.arrival_rate, F32(1.0))).astype(I32),
-            1)
-        arr = th.txn * interval + (tids * 977) % interval
-        early_t = st & (arr > now) & rate_on
-        th = th._replace(
-            phase=jnp.where(early_t, ARRIVE, th.phase),
-            work=jnp.where(early_t, arr - now, th.work))
-        st = st & ~early_t
+        with jax.named_scope("stage_txn_start"):
+            st = th.phase == START
+            past = (now >= dp.horizon) | (th.txn >= dp.txn_cap)
+            th = th._replace(phase=jnp.where(st & past, HALT, th.phase))
+            st = st & ~past
+            # fixed-TPS open loop; arrival_rate <= 0: closed loop, no gate.
+            # n_active (not the padded T) sets the per-thread arrival interval.
+            rate_on = dp.arrival_rate > 0
+            interval = jnp.maximum(
+                (dp.n_active.astype(F32)
+                 / jnp.where(rate_on, dp.arrival_rate, F32(1.0))).astype(I32),
+                1)
+            arr = th.txn * interval + (tids * 977) % interval
+            early_t = st & (arr > now) & rate_on
+            th = th._replace(
+                phase=jnp.where(early_t, ARRIVE, th.phase),
+                work=jnp.where(early_t, arr - now, th.work))
+            st = st & ~early_t
         with jax.named_scope("stage_gen_txn"):
             keys, iswr, dup, lastu, nops = gen_txn_dyn(
                 stat.kind, R, L, dp.wl, tids, th.txn,
                 acq_order=dp.ordered_acquire,
                 skip_analysis="dup_analysis" in ablate)
-        wab = will_abort_dyn(dp.wl.seed, dp.p_abort, tids, th.txn)
-        sel = st[:, None]
-        th = th._replace(
-            keys=jnp.where(sel, keys, th.keys),
-            iswr=jnp.where(sel, iswr, th.iswr),
-            dup=jnp.where(sel, dup, th.dup),
-            lastu=jnp.where(sel, lastu, th.lastu),
-            nops=jnp.where(st, nops, th.nops),
-            willab=jnp.where(st, wab, th.willab),
-            tstart=jnp.where(st & ~th.retry, now, th.tstart),
-            op=jnp.where(st, 0, th.op))
+        with jax.named_scope("stage_txn_start"):
+            wab = will_abort_dyn(dp.wl.seed, dp.p_abort, tids, th.txn)
+            sel = st[:, None]
+            th = th._replace(
+                keys=jnp.where(sel, keys, th.keys),
+                iswr=jnp.where(sel, iswr, th.iswr),
+                dup=jnp.where(sel, dup, th.dup),
+                lastu=jnp.where(sel, lastu, th.lastu),
+                nops=jnp.where(st, nops, th.nops),
+                willab=jnp.where(st, wab, th.willab),
+                tstart=jnp.where(st & ~th.retry, now, th.tstart),
+                op=jnp.where(st, 0, th.op))
 
         # ------------------------------------------------ 8. begin next op
         # Threads entering a new op (fresh txns or op-advance) either take a
         # ticket (effective write) or execute directly (read / dup write).
-        begin = st | next_op
-        bkey = cur(th.keys, th.op)
-        bwr = cur(th.iswr, th.op) & ~cur(th.dup, th.op)
-        need_ticket = begin & bwr
-        direct = begin & ~bwr
-        rd_cost = jnp.where(cur(th.iswr, th.op), dp.op_exec, dp.read_exec)
-        th = th._replace(
-            phase=jnp.where(direct, EXEC, th.phase),
-            work=jnp.where(direct, rd_cost, th.work),
-            # direct exec pays no grant overhead: no detection to attribute
-            detleft=jnp.where(direct, 0, th.detleft))
+        with jax.named_scope("stage_op_begin"):
+            begin = st | next_op
+            bkey = cur(th.keys, th.op)
+            bwr = cur(th.iswr, th.op) & ~cur(th.dup, th.op)
+            need_ticket = begin & bwr
+            direct = begin & ~bwr
+            rd_cost = jnp.where(cur(th.iswr, th.op), dp.op_exec, dp.read_exec)
+            th = th._replace(
+                phase=jnp.where(direct, EXEC, th.phase),
+                work=jnp.where(direct, rd_cost, th.work),
+                # direct exec pays no grant overhead: no detection to attribute
+                detleft=jnp.where(direct, 0, th.detleft))
 
         # FIFO ticket assignment with same-tick ranking (sort by key).
         # Sentinel key R sorts all non-takers after every real key so they
@@ -1003,14 +1053,15 @@ def _make_step_events(stat: StaticShape, dp: DynParams, until=None,
                 rank_sorted = idx - seg_start
                 rank = jnp.zeros((T,), I32).at[order].set(
                     rank_sorted.astype(I32))
-        tkt = jnp.where(need_ticket, rows.nt[bkey] + rank, NOTK)
-        counts = _seg_sum(jnp.ones_like(bkey), bkey, R, need_ticket)
-        rows = rows._replace(nt=rows.nt + counts)
-        th = th._replace(
-            ticket=th.ticket.at[tids, jnp.clip(th.op, 0, L - 1)].set(
-                jnp.where(need_ticket, tkt, cur(th.ticket, th.op))),
-            phase=jnp.where(need_ticket, WAIT, th.phase),
-            wstart=jnp.where(need_ticket, now, th.wstart))
+        with jax.named_scope("stage_op_begin"):
+            tkt = jnp.where(need_ticket, rows.nt[bkey] + rank, NOTK)
+            counts = _seg_sum(jnp.ones_like(bkey), bkey, R, need_ticket)
+            rows = rows._replace(nt=rows.nt + counts)
+            th = th._replace(
+                ticket=th.ticket.at[tids, jnp.clip(th.op, 0, L - 1)].set(
+                    jnp.where(need_ticket, tkt, cur(th.ticket, th.op))),
+                phase=jnp.where(need_ticket, WAIT, th.phase),
+                wstart=jnp.where(need_ticket, now, th.wstart))
 
         # ------------------------------------------------ 9. hotspot detect
         # without a hotspot queue rows never turn hot, so the off branch
@@ -1142,8 +1193,10 @@ def _run_core(stat: StaticShape, dp: DynParams, s0: SimState,
     ``Globals.iters`` only differs when a fully-idle stall window spans
     boundaries (the jump splits into one iteration per segment).
     """
-    step = _make_step(stat, dp, until=until)
-    return lax.while_loop(_make_cond(dp, until=until), step, s0)
+    with jax.named_scope("loop_setup"):
+        step = _make_step(stat, dp, until=until)
+        cond = _make_cond(dp, until=until)
+    return lax.while_loop(cond, step, s0)
 
 
 def _make_cond(dp: DynParams, until=None):
@@ -1151,13 +1204,14 @@ def _make_cond(dp: DynParams, until=None):
     stop_time = _stop_time(dp)
 
     def cond(s: SimState):
-        live = (s.th.phase != HALT).any()
-        running = jnp.where(dp.drain,
-                            live & (s.g.now < stop_time),
-                            s.g.now < dp.horizon)
-        if until is not None:
-            running = running & (s.g.now < until)
-        return running & (s.g.iters < dp.max_iters)
+        with jax.named_scope("loop_cond"):
+            live = (s.th.phase != HALT).any()
+            running = jnp.where(dp.drain,
+                                live & (s.g.now < stop_time),
+                                s.g.now < dp.horizon)
+            if until is not None:
+                running = running & (s.g.now < until)
+            return running & (s.g.iters < dp.max_iters)
 
     return cond
 
@@ -1240,6 +1294,7 @@ def _q_bucket(v):
     return jnp.clip(jnp.where(v <= 0, 0, f.astype(I32) + 1), 0, N_QHIST - 1)
 
 
+@jax.named_scope("seg_snapshot")
 def _snapshot(stat: StaticShape, dp: DynParams, s: SimState) -> SegSnapshot:
     d = _derive(stat, dp, s.th, s.rows)
     waitish = ((s.th.phase == WAIT) | (s.th.phase == CWAIT)
@@ -1298,9 +1353,17 @@ def run_segment(stat: StaticShape, dp: DynParams, state: SimState,
 
 
 def run_sim(cfg: EngineConfig) -> SimState:
-    """Run a simulation to completion and return the final state."""
-    stat, dp = split_config(cfg)
-    return _run_dyn(stat, dp, init_state_dyn(stat, dp))
+    """Run a simulation to completion and return the final state.
+
+    The host work of the call sits in ``repro.engine.*`` profiler spans
+    (no-ops unless ``jax.profiler`` is tracing); ``run`` covers the enqueue
+    only, the device runs on after it returns."""
+    with TraceAnnotation("repro.engine.split_config"):
+        stat, dp = split_config(cfg)
+    with TraceAnnotation("repro.engine.init_state"):
+        s0 = init_state_dyn(stat, dp)
+    with TraceAnnotation("repro.engine.run"):
+        return _run_dyn(stat, dp, s0)
 
 
 def simulate(protocol: str, workload: WorkloadSpec, n_threads: int,

@@ -15,9 +15,9 @@ Three parts (DESIGN.md §11):
 
 :mod:`.compile_log` is the shared compile counter + compile-time
 telemetry benchmarks use to put recompile regressions on the perf
-trajectory; :mod:`.prof` is the stage-ablation step profiler
-(DESIGN.md §12) that attributes per-iteration wall cost to engine
-stages.
+trajectory; :mod:`.prof` maps the instructions of a compiled engine
+program to the engine's stage scopes (DESIGN.md §12.1), which turns a
+device trace's per-operation time into per-stage time.
 
 **Hotspot attribution** (DESIGN.md §14): :mod:`.hotspot` ranks the
 engine's per-record contention accumulator (``Globals.ca``, gated by
@@ -29,8 +29,7 @@ matrix, per-record blame table, and the longest blocking chain.
 from . import blame, breakdown, compile_log, export, hotspot, prof, trace
 from .breakdown import (breakdown_row, check_conservation, fractions,
                         tick_sum)
-from .prof import (STAGE_NOOPS, StageCost, StepProfile, profile_row,
-                   profile_step, rank_table)
+from .prof import STAGE_NOOPS, mixed_fusions, op_stages, stage_map
 from .export import (breakdown_table, dump_chrome_trace, to_chrome_trace,
                      wait_profile)
 from .blame import (BlameResult, blame_matrix, blame_table, critical_path)
@@ -46,8 +45,7 @@ __all__ = [
     "blame", "breakdown", "compile_log", "export", "hotspot", "prof",
     "trace",
     "breakdown_row", "check_conservation", "fractions", "tick_sum",
-    "STAGE_NOOPS", "StageCost", "StepProfile", "profile_row",
-    "profile_step", "rank_table",
+    "STAGE_NOOPS", "mixed_fusions", "op_stages", "stage_map",
     "breakdown_table", "dump_chrome_trace", "to_chrome_trace",
     "wait_profile",
     "BlameResult", "blame_matrix", "blame_table", "critical_path",

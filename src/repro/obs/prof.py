@@ -1,43 +1,31 @@
-"""Per-stage step profiler: where each engine iteration's wall time goes.
+"""Engine stages of a compiled program, read from its HLO metadata.
 
-The engine step is one fused XLA program inside a ``lax.while_loop`` —
-``jax.profiler`` spans cannot see inside it, and host timers only see the
-whole iteration. Attribution therefore works by *stage ablation*
-(DESIGN.md §12): for every stage in ``engine.PROF_STAGES`` we build a
-step variant with that stage's compute replaced by its no-op stand-in
-(``_make_step_events(..., ablate={stage})``), let XLA dead-code-eliminate
-the stage, and difference steady-state per-iteration wall time against
-the full step on the *same* warmed ``SimState`` input:
+Every instruction of an engine program sits under one of the
+``jax.named_scope`` labels in ``engine.STAGE_SCOPES`` (DESIGN.md §12.1),
+and XLA keeps the label in the instruction's ``op_name`` metadata through
+optimisation and fusion. A device trace names each operation it timed by
+its HLO instruction (``fusion.341``) and nothing else, so
+:func:`stage_map` of the executable that ran turns per-operation device
+time into per-stage device time. The benchmark's per-stage metrics read
+it (``bench/program_trace.py``).
 
-    cost(stage) ≈ us_per_iter(full) - us_per_iter(ablated)
+Scopes can nest (``seg_snapshot`` calls ``_derive``, which the loop body
+runs under ``stage_derive``); the outermost stage scope of an
+instruction's ``op_name`` wins. A fusion is charged by its own metadata;
+:func:`mixed_fusions` names the fusions whose fused computation holds
+instructions of more than one stage, which bounds how precise the
+attribution is.
 
-Compile-key discipline matches the engine: the ablation set is static,
-so each variant is exactly one executable (asserted), and the full
-variant is byte-for-byte the production step. The stand-ins are chosen
-so that under a designated no-op config the ablated step is *bit-exact*
-with the full one (tests/test_prof.py) — the measured difference is
-attributable to the stage's compute, not to semantic drift.
-
-Caveats (DESIGN.md §12): XLA fuses across stage boundaries, so ablation
-measures "what the program saves without this stage", which can exceed
-or undercut a naive op-count share; negative diffs (noise on shared
-fusions) clamp to zero and the unattributed remainder is reported as the
-``other`` pseudo-stage, so fractions always sum to 1.
+``STAGE_NOOPS`` documents the engine's ablation seam
+(``engine.PROF_STAGES``), which the benchmark's control builds on: for
+each stage, the configuration under which switching it off is a
+bit-exact no-op (asserted in tests/test_prof.py).
 """
 from __future__ import annotations
 
-import dataclasses
-import time
-from typing import Sequence
+import re
 
-import jax
-import jax.numpy as jnp
-
-from repro.core.lock import engine as _engine
-from repro.core.lock.engine import (DynParams, EngineConfig, PROF_STAGES,
-                                    SimState, StaticShape, init_state_dyn,
-                                    split_config)
-from repro.obs import compile_log
+from repro.core.lock.engine import PROF_STAGES, STAGE_SCOPES
 
 # Human-readable note per stage: the config under which its ablation is a
 # bit-exact no-op (the parity contract tests/test_prof.py asserts), and
@@ -59,132 +47,75 @@ STAGE_NOOPS = {
 }
 assert set(STAGE_NOOPS) == set(PROF_STAGES)
 
-
-@dataclasses.dataclass(frozen=True)
-class StageCost:
-    stage: str
-    us_per_iter: float          # attributed cost (clamped >= 0)
-    fraction: float             # of the full step; all rows sum to 1.0
-
-
-@dataclasses.dataclass(frozen=True)
-class StepProfile:
-    protocol: str
-    stat: StaticShape
-    us_per_iter: float          # full-step steady-state per-iteration wall
-    stages: tuple[StageCost, ...]   # ranked by cost desc, ends with residual
-    n_iters: int
-    repeats: int
-    compiles: int               # executables built (len(stages_measured)+1)
-
-    @property
-    def dominant(self) -> StageCost:
-        """Largest *real* stage (the residual never dominates a report)."""
-        real = [s for s in self.stages if s.stage != "other"]
-        return max(real, key=lambda s: s.us_per_iter)
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) ")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = (.*)$")
+_OPCODE = re.compile(r" ([a-z][a-z0-9-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%([^\s,]+)")
+_SCOPES = frozenset(STAGE_SCOPES)
 
 
-def make_iter_runner(stat: StaticShape, dp: DynParams, n_iters: int,
-                     ablate: frozenset = frozenset()):
-    """Jit a ``SimState -> SimState`` running ``n_iters`` step iterations.
+def _stage(op_name: str) -> str | None:
+    """The outermost stage scope in an ``op_name`` path, or None."""
+    for part in op_name.split("/"):
+        if part in _SCOPES:
+            return part
+    return None
 
-    One executable per (stat, n_iters, ablate) — the profiler's unit of
-    measurement. Registered with :mod:`repro.obs.compile_log` so bench
-    runs count profiler compiles like any other entry point.
+
+def _instructions(hlo_text: str):
+    """Yield (computation, instruction, opcode, op_name | None, calls |
+    None) for every instruction of an HLO module's text."""
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        name, rest = m.groups()
+        op = _OPCODE.search(" " + rest)
+        meta = _OP_NAME.search(rest)
+        calls = _CALLS.search(rest)
+        yield (comp, name, op.group(1) if op else "",
+               meta.group(1) if meta else None,
+               calls.group(1) if calls else None)
+
+
+def op_stages(hlo_text: str) -> dict:
+    """``{instruction: stage}`` of every instruction in an HLO module's
+    text: the outermost ``engine.STAGE_SCOPES`` label of its ``op_name``,
+    or None where it has none (XLA's own copies and tuples, ops outside
+    any engine scope)."""
+    return {name: _stage(op_name) if op_name else None
+            for _, name, _, op_name, _ in _instructions(hlo_text)}
+
+
+def mixed_fusions(hlo_text: str) -> dict:
+    """``{fusion: stages}`` of the fusions whose fused computation holds
+    instructions of more than one stage (None counts as a stage: code in
+    no scope). Instructions without metadata are left out."""
+    by_comp: dict = {}
+    fusions = []
+    for comp, name, opcode, op_name, calls in _instructions(hlo_text):
+        if op_name is not None:
+            by_comp.setdefault(comp, set()).add(_stage(op_name))
+        if opcode == "fusion" and calls:
+            fusions.append((name, calls))
+    out = {}
+    for name, calls in fusions:
+        stages = by_comp.get(calls, set())
+        if len(stages) > 1:
+            out[name] = tuple(sorted(stages, key=lambda s: s or ""))
+    return out
+
+
+def stage_map(jitted, *args) -> dict:
+    """:func:`op_stages` of the executable ``jitted`` runs on ``args``.
+
+    Lowers and compiles ``jitted`` for ``args``; with the persistent
+    compile cache warm this loads the executable that ran instead of
+    compiling one. Call it outside any measured window: it may compile.
     """
-    step = _engine._make_step(stat, dp, ablate=ablate)
-
-    @jax.jit
-    def run(st: SimState) -> SimState:
-        return jax.lax.fori_loop(0, n_iters, lambda _, s: step(s), st)
-
-    compile_log.register(run)
-    return run
-
-
-def _block(st: SimState) -> None:
-    for leaf in jax.tree_util.tree_leaves(st):
-        leaf.block_until_ready()
-
-
-def _time_us_per_iter(run, st: SimState, n_iters: int, repeats: int) -> float:
-    """Best-of-``repeats`` per-iteration wall, first (compile) call excluded."""
-    _block(run(st))             # compile + warm the executable
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        _block(run(st))
-        best = min(best, time.perf_counter() - t0)
-    return best * 1e6 / n_iters
-
-
-def profile_step(cfg: EngineConfig, *, n_iters: int = 256,
-                 warmup_rounds: int = 1, repeats: int = 3,
-                 stages: Sequence[str] = PROF_STAGES) -> StepProfile:
-    """Attribute the engine step's per-iteration wall cost to its stages.
-
-    Builds one executable per ablation plus the full step, warms a
-    steady-state ``SimState`` under the full step (``warmup_rounds`` x
-    ``n_iters`` iterations — reusing the full executable keeps the
-    compile count at exactly ``len(stages) + 1``), feeds the *same*
-    state to every variant, and differences best-of-``repeats``
-    ``us_per_iter``. The residual the ablations cannot explain is the
-    ``other`` row; fractions sum to exactly 1.
-    """
-    unknown = set(stages) - set(PROF_STAGES)
-    if unknown:
-        raise ValueError(f"unknown stages: {sorted(unknown)}")
-    stat, dp = split_config(cfg)
-    st0 = init_state_dyn(stat, dp)
-
-    full = make_iter_runner(stat, dp, n_iters)
-    # warm into steady state so every variant sees live contention, not
-    # the all-START first ticks
-    warm = st0
-    for _ in range(warmup_rounds):
-        warm = full(warm)
-    _block(warm)
-    full_us = _time_us_per_iter(full, warm, n_iters, repeats)
-
-    costs: dict[str, float] = {}
-    n_exec = 1
-    for stage in stages:
-        run = make_iter_runner(stat, dp, n_iters, ablate=frozenset({stage}))
-        n_exec += 1
-        abl_us = _time_us_per_iter(run, warm, n_iters, repeats)
-        costs[stage] = max(full_us - abl_us, 0.0)
-        assert run._cache_size() == 1, \
-            f"ablation {stage}: expected 1 executable, got {run._cache_size()}"
-    assert full._cache_size() == 1
-
-    other = max(full_us - sum(costs.values()), 0.0)
-    total = sum(costs.values()) + other
-    total = total or 1.0        # degenerate all-zero measurement
-    ranked = sorted(costs.items(), key=lambda kv: -kv[1])
-    rows = tuple(StageCost(k, v, v / total) for k, v in ranked)
-    rows += (StageCost("other", other, other / total),)
-    return StepProfile(protocol=cfg.protocol.name, stat=stat,
-                       us_per_iter=full_us, stages=rows,
-                       n_iters=n_iters, repeats=repeats, compiles=n_exec)
-
-
-def rank_table(prof: StepProfile) -> str:
-    """Ranked per-stage cost table, one profile per call."""
-    s = prof.stat
-    head = (f"step profile: {prof.protocol} T={s.n_threads} L={s.txn_len} "
-            f"R={s.n_rows}  us_per_iter={prof.us_per_iter:.2f} "
-            f"(n_iters={prof.n_iters}, best of {prof.repeats})")
-    lines = [head, f"{'stage':<16}{'us/iter':>10}{'fraction':>10}"]
-    for row in prof.stages:
-        lines.append(f"{row.stage:<16}{row.us_per_iter:>10.3f}"
-                     f"{row.fraction:>10.3f}")
-    d = prof.dominant
-    lines.append(f"dominant: {d.stage} ({d.fraction:.0%} of step)")
-    return "\n".join(lines)
-
-
-def profile_row(name: str, prof: StepProfile) -> str:
-    """Benchmark CSV row ``name,us_per_iter,stage=frac;...;dominant=...``."""
-    body = ";".join(f"{r.stage}={r.fraction:.4f}" for r in prof.stages)
-    return (f"{name},{prof.us_per_iter:.3f},{body};"
-            f"dominant={prof.dominant.stage};compiles={prof.compiles}")
+    return op_stages(jitted.lower(*args).compile().as_text())

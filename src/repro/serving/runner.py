@@ -52,6 +52,7 @@ from typing import Iterable
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.lock import engine as _engine
 from repro.core.lock.costs import CostModel
@@ -389,6 +390,71 @@ def _revive(packed, width: int, rows: np.ndarray):
     return packed._replace(th=packed.th._replace(phase=new))
 
 
+def _open_lanes(lanes, bcells, presets, seg_ticks: int, pad_t: int,
+                pad_l: int):
+    """Boundary 0 of a bucket: admit the opening arrivals, dispatch the
+    first credits, then build the initial device states (phase START is
+    correct everywhere: credit-less slots self-HALT on their first quota
+    check, credited slots run). Returns (stat, states, the t=0
+    admissions folded into record 0)."""
+    stat = None
+    states = []
+    prologue = []
+    for ln, c, p in zip(lanes, bcells, presets):
+        prologue.append(ln.admit(0))
+        ln.dispatch()
+        ln.check_conservation("t=0")
+        st, dp0 = _engine.split_config(_cell_config(c, p, seg_ticks),
+                                       pad_threads=pad_t, pad_len=pad_l)
+        assert stat is None or st == stat
+        stat = st
+        s0 = _engine.init_state_dyn(st, dp0)
+        states.append(s0)
+        ln.g_prev = jax.device_get(s0.g)
+        ln.all_ordered = bool(preset_params(p).ordered_acquire)
+    return stat, states, prologue
+
+
+def _lane_boundary(ln: _Lane, c: ServeCell, p: str, k: int, until: int,
+                  g_now, txn_now, snap, prologue) -> None:
+    """One lane at boundary ``k``: retire completions, admit and dispatch,
+    check conservation, and append the boundary's records. ``prologue``
+    holds the t=0 admissions at boundary 0 (None after), so the records
+    sum to the lane totals."""
+    t0, t1 = int(ln.g_prev.now), int(g_now.now)
+    n_done, window = ln.retire(txn_now[:c.n_threads], t1)
+    n_arr, n_rej, n_shed = ln.admit(until)
+    if prologue is not None:
+        p_arr, p_rej, p_shed = prologue
+        n_arr += p_arr
+        n_rej += p_rej
+        n_shed += p_shed
+    ln.dispatch()
+    ln.check_conservation(f"t={until}")
+    r = extract_segment(p, c.n_threads, ln.g_prev, g_now)
+    ln.history.append(SegmentRecord(
+        index=k, t0=t0, t1=t1, preset=p, metrics=r,
+        max_qlen=int(snap.max_qlen),
+        n_hot=int(snap.n_hot),
+        n_live=int(snap.n_live),
+        n_waiting=int(snap.n_waiting),
+        wait_hist=tuple(int(v) for v in snap.wait_hist),
+        occ_hist=tuple(int(v) for v in snap.occ_hist)))
+    ln.records.append(ServingRecord(
+        index=k, t0=t0, t1=t1, preset=p, metrics=r,
+        arrived=n_arr, rejected=n_rej, shed=n_shed,
+        completed=n_done, qlen=len(ln.queue),
+        in_flight=ln.in_flight,
+        p50_us=_pctl(window, 50.0),
+        p99_us=_pctl(window, 99.0),
+        p999_us=_pctl(window, 99.9),
+        sla_miss=sum(1 for u in window
+                     if c.sla_us > 0 and u > c.sla_us),
+        max_qlen=int(snap.max_qlen),
+        n_waiting=int(snap.n_waiting)))
+    ln.g_prev = g_now
+
+
 def serve(cells: Iterable[ServeCell], *, seg_ticks: int,
           chunk_size: int | None = None, return_states: bool = False,
           keep_responses: bool = False, metrics_registry=None,
@@ -456,114 +522,80 @@ def serve(cells: Iterable[ServeCell], *, seg_ticks: int,
         presets = [c.policy.decide(0, []) if c.policy else c.preset
                    for c in bcells]
 
-        # boundary 0: admit the opening arrivals, dispatch the first
-        # credits, then build the initial device states (phase START is
-        # correct everywhere: credit-less slots self-HALT on their first
-        # quota check, credited slots run)
-        stat = None
-        states = []
-        prologue = []           # t=0 admissions, folded into record 0
-        for ln, c, p in zip(lanes, bcells, presets):
-            prologue.append(ln.admit(0))
-            ln.dispatch()
-            ln.check_conservation("t=0")
-            st, dp0 = _engine.split_config(_cell_config(c, p, seg_ticks),
-                                           pad_threads=pad_t,
-                                           pad_len=pad_l)
-            assert stat is None or st == stat
-            stat = st
-            s0 = _engine.init_state_dyn(st, dp0)
-            states.append(s0)
-            ln.g_prev = jax.device_get(s0.g)
-            ln.all_ordered = bool(preset_params(p).ordered_acquire)
-
         groups = [list(range(lo, min(lo + chunk_size, G)))
                   for lo in range(0, G, max(chunk_size, 1))]
         gpacked: list = [None] * len(groups)
         gwidth: list = [0] * len(groups)
+        # profiler spans of this bucket's boundaries (no-ops untraced)
+        tag = f"{kind}:{n_rows}:{pad_t}:{pad_l}"
+
+        def span(name: str, k: int):
+            return TraceAnnotation(f"repro.serve.{name}", boundary=k,
+                                   bucket=tag)
 
         for k, until in enumerate(bounds):
-            if k:
-                presets = [c.policy.decide(k, ln.history)
-                           if c.policy else c.preset
-                           for c, ln in zip(bcells, lanes)]
-            dps = []
-            for ln, c, p in zip(lanes, bcells, presets):
-                if k and not switch_safe(p) and not ln.all_ordered:
-                    # same rule as run_governed; serving workloads are
-                    # static so the rank-rotation clause can't trip
-                    raise ValueError(
-                        f"serve cell {c.name!r}: policy {c.label()!r} "
-                        f"runs resolver-free preset {p!r} at boundary "
-                        f"{k} after an unordered-preset segment; "
-                        "inherited out-of-order locks can cycle "
-                        "unresolvably — use 'brook_guard' "
-                        "(DESIGN.md §9.2)")
-                ln.all_ordered &= bool(preset_params(p).ordered_acquire)
-                dp = _engine.split_config(_cell_config(c, p, seg_ticks),
-                                          pad_threads=pad_t,
-                                          pad_len=pad_l)[1]
-                dps.append(dp._replace(txn_cap=ln.cap_vector(pad_t)))
+            with span("rebuild", k):
+                if k:
+                    presets = [c.policy.decide(k, ln.history)
+                               if c.policy else c.preset
+                               for c, ln in zip(bcells, lanes)]
+                else:
+                    stat, states, prologue = _open_lanes(
+                        lanes, bcells, presets, seg_ticks, pad_t, pad_l)
+                dps = []
+                for ln, c, p in zip(lanes, bcells, presets):
+                    if k and not switch_safe(p) and not ln.all_ordered:
+                        # same rule as run_governed; serving workloads are
+                        # static so the rank-rotation clause can't trip
+                        raise ValueError(
+                            f"serve cell {c.name!r}: policy {c.label()!r} "
+                            f"runs resolver-free preset {p!r} at boundary "
+                            f"{k} after an unordered-preset segment; "
+                            "inherited out-of-order locks can cycle "
+                            "unresolvably — use 'brook_guard' "
+                            "(DESIGN.md §9.2)")
+                    ln.all_ordered &= bool(preset_params(p).ordered_acquire)
+                    dp = _engine.split_config(_cell_config(c, p, seg_ticks),
+                                              pad_threads=pad_t,
+                                              pad_len=pad_l)[1]
+                    dps.append(dp._replace(txn_cap=ln.cap_vector(pad_t)))
 
             for gi, grp in enumerate(groups):
-                packed = gpacked[gi]
-                if packed is not None:
-                    rows = np.stack([lanes[j].revive_row(pad_t)
-                                     for j in grp]
-                                    + [np.zeros(pad_t, dtype=bool)]
-                                    * (gwidth[gi] - len(grp)))
-                    packed = _revive(packed, gwidth[gi], rows)
-                gpacked[gi], snaps, w = run_packed_segment(
-                    stat, [dps[j] for j in grp],
-                    [states[j] for j in grp], [until] * len(grp),
-                    packed=packed)
-                gwidth[gi] = w
-                g_host, txn_host, snap_host = jax.device_get(
-                    (gpacked[gi].g, gpacked[gi].th.txn, snaps))
-                for lane_i, j in enumerate(grp):
-                    ln, c, p = lanes[j], bcells[j], presets[j]
-                    if w == 1:
-                        g_now, txn_now, snap = g_host, txn_host, snap_host
-                    else:
-                        g_now = _take(g_host, lane_i)
-                        txn_now = txn_host[lane_i]
-                        snap = _take(snap_host, lane_i)
-                    t0, t1 = int(ln.g_prev.now), int(g_now.now)
-                    n_done, window = ln.retire(
-                        txn_now[:c.n_threads], t1)
-                    n_arr, n_rej, n_shed = ln.admit(until)
-                    if k == 0:      # attribute the t=0 prologue here so
-                                    # the records sum to the lane totals
-                        p_arr, p_rej, p_shed = prologue[j]
-                        n_arr += p_arr
-                        n_rej += p_rej
-                        n_shed += p_shed
-                    ln.dispatch()
-                    ln.check_conservation(f"t={until}")
-                    r = extract_segment(p, c.n_threads, ln.g_prev, g_now)
-                    ln.history.append(SegmentRecord(
-                        index=k, t0=t0, t1=t1, preset=p, metrics=r,
-                        max_qlen=int(snap.max_qlen),
-                        n_hot=int(snap.n_hot),
-                        n_live=int(snap.n_live),
-                        n_waiting=int(snap.n_waiting),
-                        wait_hist=tuple(int(v) for v in snap.wait_hist),
-                        occ_hist=tuple(int(v) for v in snap.occ_hist)))
-                    ln.records.append(ServingRecord(
-                        index=k, t0=t0, t1=t1, preset=p, metrics=r,
-                        arrived=n_arr, rejected=n_rej, shed=n_shed,
-                        completed=n_done, qlen=len(ln.queue),
-                        in_flight=ln.in_flight,
-                        p50_us=_pctl(window, 50.0),
-                        p99_us=_pctl(window, 99.0),
-                        p999_us=_pctl(window, 99.9),
-                        sla_miss=sum(1 for u in window
-                                     if c.sla_us > 0 and u > c.sla_us),
-                        max_qlen=int(snap.max_qlen),
-                        n_waiting=int(snap.n_waiting)))
-                    if metrics_registry is not None:
-                        metrics_registry.observe(c.name, ln.records[-1])
-                    ln.g_prev = g_now
+                with span("segment", k):
+                    packed = gpacked[gi]
+                    if packed is not None:
+                        rows = np.stack([lanes[j].revive_row(pad_t)
+                                         for j in grp]
+                                        + [np.zeros(pad_t, dtype=bool)]
+                                        * (gwidth[gi] - len(grp)))
+                        packed = _revive(packed, gwidth[gi], rows)
+                    gpacked[gi], snaps, w = run_packed_segment(
+                        stat, [dps[j] for j in grp],
+                        [states[j] for j in grp], [until] * len(grp),
+                        packed=packed)
+                    gwidth[gi] = w
+                with span("device_get", k):
+                    g_host, txn_host, snap_host = jax.device_get(
+                        (gpacked[gi].g, gpacked[gi].th.txn, snaps))
+                with span("lanes", k):
+                    for lane_i, j in enumerate(grp):
+                        ln, c, p = lanes[j], bcells[j], presets[j]
+                        if w == 1:
+                            g_now, txn_now, snap = (g_host, txn_host,
+                                                    snap_host)
+                        else:
+                            g_now = _take(g_host, lane_i)
+                            txn_now = txn_host[lane_i]
+                            snap = _take(snap_host, lane_i)
+                        _lane_boundary(ln, c, p, k, until, g_now,
+                                      txn_now, snap,
+                                      prologue[j] if k == 0 else None)
+                # after the span: the registry's own work is not the
+                # serving layer's
+                if metrics_registry is not None:
+                    for j in grp:
+                        metrics_registry.observe(bcells[j].name,
+                                                 lanes[j].records[-1])
 
         if return_states:
             for gi, grp in enumerate(groups):
