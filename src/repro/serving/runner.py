@@ -41,6 +41,11 @@ and it re-decides the preset each boundary from the same
 resolver-free-preset switch rules (brook, DESIGN.md §9.2) are enforced
 here identically. Workloads don't drift under serving, so only the
 ordered-prefix rule can trip (the chop rank table is static).
+
+A lane's engine parameters are built once per distinct ``EngineConfig``
+per call (memoised on the config value; only ``txn_cap`` changes per
+boundary), so a boundary re-uploads the credit vector, not the R-row
+tables. Each build is marked ``repro.engine.split_config`` in a trace.
 """
 from __future__ import annotations
 
@@ -390,12 +395,12 @@ def _revive(packed, width: int, rows: np.ndarray):
     return packed._replace(th=packed.th._replace(phase=new))
 
 
-def _open_lanes(lanes, bcells, presets, seg_ticks: int, pad_t: int,
-                pad_l: int):
+def _open_lanes(lanes, bcells, presets, params):
     """Boundary 0 of a bucket: admit the opening arrivals, dispatch the
     first credits, then build the initial device states (phase START is
     correct everywhere: credit-less slots self-HALT on their first quota
-    check, credited slots run). Returns (stat, states, the t=0
+    check, credited slots run). ``params(cell, preset, k)`` is the
+    bucket's memoised ``split_config``. Returns (stat, states, the t=0
     admissions folded into record 0)."""
     stat = None
     states = []
@@ -404,8 +409,7 @@ def _open_lanes(lanes, bcells, presets, seg_ticks: int, pad_t: int,
         prologue.append(ln.admit(0))
         ln.dispatch()
         ln.check_conservation("t=0")
-        st, dp0 = _engine.split_config(_cell_config(c, p, seg_ticks),
-                                       pad_threads=pad_t, pad_len=pad_l)
+        st, dp0 = params(c, p, 0)
         assert stat is None or st == stat
         stat = st
         s0 = _engine.init_state_dyn(st, dp0)
@@ -533,6 +537,21 @@ def serve(cells: Iterable[ServeCell], *, seg_ticks: int,
             return TraceAnnotation(f"repro.serve.{name}", boundary=k,
                                    bucket=tag)
 
+        # a lane's engine parameters are a pure function of its
+        # EngineConfig, fixed within a call unless its policy switches
+        # preset: build each distinct config once (the R-row Zipf CDF and
+        # chop rank tables dominate a build) and reuse its device arrays
+        built: dict[EngineConfig, tuple] = {}
+
+        def params(c: ServeCell, p: str, k: int):
+            cfg = _cell_config(c, p, seg_ticks)
+            if cfg not in built:
+                with TraceAnnotation("repro.engine.split_config",
+                                     boundary=k, bucket=tag):
+                    built[cfg] = _engine.split_config(
+                        cfg, pad_threads=pad_t, pad_len=pad_l)
+            return built[cfg]
+
         for k, until in enumerate(bounds):
             with span("rebuild", k):
                 if k:
@@ -541,7 +560,7 @@ def serve(cells: Iterable[ServeCell], *, seg_ticks: int,
                                for c, ln in zip(bcells, lanes)]
                 else:
                     stat, states, prologue = _open_lanes(
-                        lanes, bcells, presets, seg_ticks, pad_t, pad_l)
+                        lanes, bcells, presets, params)
                 dps = []
                 for ln, c, p in zip(lanes, bcells, presets):
                     if k and not switch_safe(p) and not ln.all_ordered:
@@ -555,9 +574,7 @@ def serve(cells: Iterable[ServeCell], *, seg_ticks: int,
                             "unresolvably — use 'brook_guard' "
                             "(DESIGN.md §9.2)")
                     ln.all_ordered &= bool(preset_params(p).ordered_acquire)
-                    dp = _engine.split_config(_cell_config(c, p, seg_ticks),
-                                              pad_threads=pad_t,
-                                              pad_len=pad_l)[1]
+                    dp = params(c, p, k)[1]
                     dps.append(dp._replace(txn_cap=ln.cap_vector(pad_t)))
 
             for gi, grp in enumerate(groups):

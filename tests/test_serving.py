@@ -10,6 +10,7 @@ run of the same padded config bit-for-bit, except the diagnostic
 ``Globals.iters``, which a segment boundary may legitimately split
 (0 <= open - ref <= n_segments - 1, the run_segment contract).
 """
+import dataclasses
 import os
 
 import numpy as np
@@ -398,6 +399,90 @@ class TestGovernedServing:
                            admission="wait", max_outstanding=200)]
         with pytest.raises(ValueError, match="resolver-free"):
             serve(cells, seg_ticks=5_000)
+
+
+# ---------------------------------------------------------------------------
+# engine parameters: one build per distinct lane config per call
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def count_builds(monkeypatch):
+    """Count ``split_config`` calls by the config each one built."""
+    real = E.split_config
+    built = []
+
+    def counting(cfg, *args, **kwargs):
+        built.append(cfg)
+        return real(cfg, *args, **kwargs)
+
+    monkeypatch.setattr(E, "split_config", counting)
+    return built
+
+
+class TestParamMemo:
+    def test_one_cell_builds_once(self, count_builds):
+        cells = [ServeCell(name="one",
+                           schedule=poisson(0.002, 30_000, seed=SEED),
+                           workload=W_SMALL, n_threads=4, preset="mysql",
+                           admission="wait", max_outstanding=8)]
+        res = serve(cells, seg_ticks=5_000)
+        assert len(res.segments["one"]) == 6
+        assert len(count_builds) == 1
+
+    def test_policy_builds_each_preset_once(self, count_builds):
+        from repro.adaptive import QueueRulePolicy
+        hot = WorkloadSpec(kind="hotspot_update", txn_len=2, n_rows=2048)
+        cells = [ServeCell(name="gov", schedule=saturating(4_000, 60_000),
+                           workload=hot, n_threads=32, preset="o2",
+                           policy=QueueRulePolicy(), admission="wait",
+                           max_outstanding=200)]
+        res = serve(cells, seg_ticks=10_000)
+        presets = [r["preset"] for r in res.segments["gov"]]
+        assert set(presets) == {"o2", "group"}
+        assert len(count_builds) == 2
+        assert len(set(count_builds)) == 2
+
+    def test_bucket_mates_build_once_each(self, count_builds):
+        cells = [ServeCell(name=f"c{i}",
+                           schedule=poisson(0.002, 30_000, seed=i),
+                           workload=dataclasses.replace(W_SMALL, seed=i),
+                           n_threads=4, preset="mysql", admission="wait",
+                           max_outstanding=8) for i in range(2)]
+        res = serve(cells, seg_ticks=5_000)
+        assert len(res.buckets) == 1 and res.buckets[0].n_points == 2
+        assert len(count_builds) == 2
+        assert {cfg.workload.seed for cfg in count_builds} == {0, 1}
+
+    def test_reused_params_serve_what_fresh_ones_do(self, count_builds,
+                                                    monkeypatch):
+        """A run that builds anew at every boundary (each lookup gets a
+        distinct key: ``EngineConfig.seed``, which ``split_config`` does
+        not read) answers bit-for-bit what the memoised run answers."""
+        from repro.serving import runner
+
+        def run():
+            cells = [ServeCell(name="p",
+                               schedule=poisson(0.003, 30_000, seed=3),
+                               workload=W_PARITY, n_threads=8,
+                               preset="mysql", admission="wait",
+                               max_outstanding=8)]
+            return serve(cells, seg_ticks=5_000, return_states=True,
+                         keep_responses=True)
+
+        memo = run()
+        assert len(count_builds) == 1
+        real, keys = runner._cell_config, iter(range(1, 10**6))
+        monkeypatch.setattr(
+            runner, "_cell_config",
+            lambda *a: dataclasses.replace(real(*a), seed=next(keys)))
+        fresh = run()
+        # boundary 0 builds twice (initial state, then the segment)
+        assert len(count_builds) == 1 + len(fresh.segments["p"]) + 1
+        assert memo.segments == fresh.segments
+        assert memo.responses == fresh.responses
+        for a, b in zip(jax.tree_util.tree_leaves(memo.states["p"]),
+                        jax.tree_util.tree_leaves(fresh.states["p"])):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------
