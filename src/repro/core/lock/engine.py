@@ -1294,6 +1294,20 @@ def _q_bucket(v):
     return jnp.clip(jnp.where(v <= 0, 0, f.astype(I32) + 1), 0, N_QHIST - 1)
 
 
+def _bucket_counts(bk, weight=None):
+    """(N_QHIST,) i32 count of each bucket in ``bk``, only where ``weight``.
+
+    Compare-and-reduce, not a scatter-add: nearly every row falls in
+    bucket 0, and the TPU runs colliding scatter updates one at a time
+    (~9 ns per row on a TPU v5e at R = 1M), while 12 compares per row
+    stream at HBM speed.
+    """
+    hit = bk[None, :] == jnp.arange(N_QHIST, dtype=I32)[:, None]
+    if weight is not None:
+        hit = hit & weight[None, :]
+    return hit.sum(axis=1, dtype=I32)
+
+
 @jax.named_scope("seg_snapshot")
 def _snapshot(stat: StaticShape, dp: DynParams, s: SimState) -> SegSnapshot:
     d = _derive(stat, dp, s.th, s.rows)
@@ -1304,9 +1318,8 @@ def _snapshot(stat: StaticShape, dp: DynParams, s: SimState) -> SegSnapshot:
         n_hot=s.rows.hot.sum().astype(I32),
         n_live=d.n_live.sum().astype(I32),
         n_waiting=waitish.sum().astype(I32),
-        wait_hist=jnp.zeros((N_QHIST,), I32).at[_q_bucket(d.n_wait)].add(1),
-        occ_hist=jnp.zeros((N_QHIST,), I32).at[_q_bucket(d.n_live)].add(
-            jnp.where(s.rows.hot, 1, 0)))
+        wait_hist=_bucket_counts(_q_bucket(d.n_wait)),
+        occ_hist=_bucket_counts(_q_bucket(d.n_live), s.rows.hot))
 
 
 def _run_seg_core(stat: StaticShape, dp: DynParams, s0: SimState,

@@ -3,7 +3,8 @@
 The TPU compiler is installed with JAX, so the programs the chip runs are
 compiled here for a chip that is described and not attached: the engine's
 single-run loop at SysBench table scale (1,000,000 rows, 1024 threads),
-the packed segment loop at the YCSB-style zipf shape, the serving
+the packed segment loop at the YCSB-style zipf shape, the served segment
+loop (whose end-of-segment snapshot must hold no scatter), the serving
 histogram fold, and the grouped-scatter Pallas kernel in compiled mode.
 A refusal the chip's compiler would raise fails here. Nothing runs.
 
@@ -12,6 +13,8 @@ imported: only one process at a time may load the TPU library, and every
 test worker imports this file. The persistent compilation cache is off
 around these compiles (a described device cannot read its entries back).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -85,6 +88,24 @@ def test_run_seg_batch_zipf_four_lanes(one_chip):
     compiled = E._run_seg_batch.lower(
         stat, _on(one_chip, dp, lanes=4), _on(one_chip, s0, lanes=4),
         untils).compile()
+    _fits(compiled)
+
+
+# a scatter whose op_name sits directly in the snapshot's scope (not in
+# its stage_derive sub-scope, which scatters only T*L updates)
+_SNAPSHOT_SCATTER = re.compile(r'op_name="[^"]*/seg_snapshot/[^"/]*scatter')
+
+
+def test_run_seg_dyn_snapshot_has_no_scatter(one_chip):
+    # the served cell's shape: 1M rows, 256 threads, four updates each
+    # (the protocol is traced data, so group compiles mysql's program)
+    stat, dp, s0 = _engine_args("zipf", 256, 4, zipf_s=0.99)
+    until = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = E._run_seg_dyn.lower(stat, _on(one_chip, dp),
+                                    _on(one_chip, s0), until).compile()
+    hlo = compiled.as_text()
+    assert "/seg_snapshot/" in hlo
+    assert not _SNAPSHOT_SCATTER.findall(hlo)
     _fits(compiled)
 
 

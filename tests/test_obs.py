@@ -4,6 +4,7 @@ and export validity."""
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -166,6 +167,70 @@ class TestSnapshotHistograms:
         assert int(occ_hist.sum()) == int(snap.n_hot)
         # contended zipf: some rows must have non-empty wait queues
         assert int(wait_hist[1:].sum()) > 0
+
+    _EDGES = np.array([0, 1] + [v for k in range(1, 12)
+                                for v in (2**k - 1, 2**k, 2**k + 1)])
+    _CASES = {
+        "edges": _EDGES,
+        "clamped": np.array([2**11 + 1, 2**12, 2**20, 2**31 - 1, 3000]),
+        "negatives": np.array([-1, -2, -(2**31), 0, 5]),
+        "mixed": np.concatenate([np.zeros(40, np.int64), _EDGES,
+                                 [-3, 2**13, 7, 7, 1]]),
+    }
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("case", sorted(_CASES))
+    def test_bucket_counts_match_bincount(self, case, masked):
+        v = jnp.asarray(self._CASES[case], jnp.int32)
+        bk = E._q_bucket(v)
+        hot = (np.arange(v.shape[0]) % 3 != 1) if masked else None
+        got = np.asarray(E._bucket_counts(
+            bk, None if hot is None else jnp.asarray(hot)))
+        bk_h = np.asarray(bk)
+        want = np.bincount(bk_h if hot is None else bk_h[hot],
+                           minlength=E.N_QHIST)
+        assert got.dtype == np.int32
+        assert np.array_equal(got, want)
+        if case == "edges" and not masked:
+            assert (want > 0).all()           # every bucket is hit
+
+    def test_batched_snapshot_equals_per_lane_and_scatter(self):
+        lanes = [("mysql", {}, 0, 30_000), ("group", {"hot_threshold": 1}, 1,
+                                             25_000),
+                 ("group", {"hot_threshold": 2}, 2, 35_000)]
+        stat, dps, s0s, untils = None, [], [], []
+        for proto, over, seed, until in lanes:
+            cfg = E.EngineConfig(
+                protocol=E.protocol_params(proto, **over), costs=CostModel(),
+                workload=WorkloadSpec(kind="zipf", txn_len=4, n_rows=512,
+                                      zipf_s=0.99, seed=seed),
+                n_threads=64, horizon=HORIZON)
+            stat, dp = E.split_config(cfg)
+            dps.append(dp)
+            s0s.append(E.init_state_dyn(stat, dp))
+            untils.append(until)
+        stack = lambda xs: jax.tree.map(lambda *a: jnp.stack(a), *xs)
+        out, snaps = E._run_seg_batch(stat, stack(dps), stack(s0s),
+                                      jnp.asarray(untils, jnp.int32))
+        for i, (dp, s0, until) in enumerate(zip(dps, s0s, untils)):
+            s, snap = E._run_seg_dyn(stat, dp, s0,
+                                     jnp.asarray(until, jnp.int32))
+            for f in ("wait_hist", "occ_hist"):
+                assert np.array_equal(np.asarray(getattr(snaps, f)[i]),
+                                      np.asarray(getattr(snap, f)))
+            # the scatter-add form the histograms replaced
+            d = E._derive(stat, dp, s.th, s.rows)
+            z = jnp.zeros((E.N_QHIST,), jnp.int32)
+            assert np.array_equal(
+                np.asarray(snap.wait_hist),
+                np.asarray(z.at[E._q_bucket(d.n_wait)].add(1)))
+            assert np.array_equal(
+                np.asarray(snap.occ_hist),
+                np.asarray(z.at[E._q_bucket(d.n_live)].add(
+                    jnp.where(s.rows.hot, 1, 0))))
+            assert int(np.asarray(snap.wait_hist)[1:].sum()) > 0
+        # the group lanes promote rows, so occ_hist is not trivially zero
+        assert int(np.asarray(snaps.occ_hist)[1:].sum()) > 0
 
 
 class TestExport:
