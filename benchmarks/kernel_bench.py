@@ -1,7 +1,6 @@
 """Kernel-level schedule comparison (CPU wall-clock is a schedule proxy,
 not a TPU claim): hotspot-grouped scatter-apply vs XLA's serialized
-duplicate-index scatter, under Zipf duplication; flash-attention kernel
-interpret sanity timing."""
+duplicate-index scatter, under Zipf duplication."""
 import time
 
 import numpy as np

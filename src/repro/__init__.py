@@ -1,3 +1,4 @@
-"""repro: TXSQL lock optimizations as a multi-pod JAX framework."""
+"""repro: a lock-manager simulator for the TXSQL concurrency-control
+protocols, compiled as JAX programs."""
 
 __version__ = "0.1.0"

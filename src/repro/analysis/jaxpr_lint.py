@@ -251,13 +251,6 @@ def _kernel_arrays(v: int, shape):
     return base * (0.01 * (v + 1)) + v
 
 
-def _build_flash(v: int):
-    q = _kernel_arrays(v, (1, 2, 16, 8))
-    k = _kernel_arrays(v + 4, (1, 2, 16, 8))
-    vv = _kernel_arrays(v + 8, (1, 2, 16, 8))
-    return (), (q, k, vv)
-
-
 def _build_grouped_scatter(v: int):
     table = _kernel_arrays(v, (32, 8))
     ids = (jnp.arange(64, dtype=I32) * (v + 3)) % 32
@@ -295,28 +288,19 @@ def default_entry_points() -> list[EntryPoint]:
     from repro.serving import runner as S
     eps.append(EntryPoint("serving._hist_add", S._hist_add,
                           _build_hist_add, expect_while=False))
-    try:    # Pallas-backed entry points; optional on exotic hosts
-        from repro.kernels.flash_attention import kernel as fk, ops as fo
-        from repro.kernels.grouped_scatter import kernel as gk, ops as go
+    from repro.kernels.grouped_scatter import kernel as gk, ops as go
 
-        def _segment_sums_g16(seg_ids, updates):
-            # num_groups is a shape argument, fixed like the other shapes
-            return gk.segment_sums(seg_ids, updates, 16, interpret=True)
+    def _segment_sums_g16(seg_ids, updates):
+        # num_groups is a shape argument, fixed like the other shapes
+        return gk.segment_sums(seg_ids, updates, 16, interpret=True)
 
-        eps += [
-            EntryPoint("kernels.flash_attention", fo.flash_attention,
-                       _build_flash, expect_while=False),
-            EntryPoint("kernels.flash_attention_bhsd",
-                       fk.flash_attention_bhsd, _build_flash,
-                       expect_while=False),
-            EntryPoint("kernels.grouped_scatter_apply",
-                       go.grouped_scatter_apply, _build_grouped_scatter,
-                       expect_while=False),
-            EntryPoint("kernels.segment_sums", _segment_sums_g16,
-                       _build_segment_sums, expect_while=False),
-        ]
-    except Exception:
-        pass
+    eps += [
+        EntryPoint("kernels.grouped_scatter_apply",
+                   go.grouped_scatter_apply, _build_grouped_scatter,
+                   expect_while=False),
+        EntryPoint("kernels.segment_sums", _segment_sums_g16,
+                   _build_segment_sums, expect_while=False),
+    ]
     return eps
 
 

@@ -20,14 +20,10 @@ Three layers, all opt-in and zero-cost when unused:
   about and warns with their names.
 
 Subsystems with their own jitted entry points register them here
-(idempotent); the core engine/aria/obs/kernels entry points are built
-in. Instance-level jits register at construction time rather than
-import time: ``launch/serve.py`` registers each ``GroupServer``'s
-``_decode`` in ``__init__`` and ``launch/train.py`` registers its init
-and train-step jits inside ``train()`` — so the accounting covers them
-exactly while they are live, and a process that never builds them pays
-nothing. The analysis linter (``repro.analysis.jaxpr_lint``) keeps its
-entry-point registry mirrored against ``_jitted()``.
+(idempotent, as ``serving/runner.py`` does for ``_hist_add``); the core
+engine/aria/obs/kernels entry points are built in. The analysis
+linter (``repro.analysis.jaxpr_lint``) keeps its entry-point registry
+mirrored against ``_jitted()``.
 """
 from __future__ import annotations
 
@@ -55,13 +51,8 @@ def _jitted() -> list:
         aria._run_seg_dyn, aria._run_seg_batch,
         trace._run_traced,
     ]
-    try:        # Pallas-backed entry points; optional on exotic hosts
-        from repro.kernels.flash_attention import kernel as fk, ops as fo
-        from repro.kernels.grouped_scatter import kernel as gk, ops as go
-        fns += [fo.flash_attention, fk.flash_attention_bhsd,
-                go.grouped_scatter_apply, gk.segment_sums]
-    except Exception:
-        pass
+    from repro.kernels.grouped_scatter import kernel as gk, ops as go
+    fns += [go.grouped_scatter_apply, gk.segment_sums]
     return fns + list(_EXTRA)
 
 

@@ -1,8 +1,8 @@
 import os
 import sys
 
-# tests must see exactly ONE device (the dry-run's 512-device trick is
-# confined to launch/dryrun.py and subprocess tests)
+# tests must see exactly ONE device (tests that need more start a
+# subprocess with their own XLA flags)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

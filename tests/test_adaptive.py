@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lock_matrix import SHAPES, each_pair
 from repro.adaptive import (DEFAULT_ARMS, EpsilonGreedyPolicy, FixedPolicy,
                             GovernorCell, Policy, QueueRulePolicy,
                             SegmentRecord, preset_timeline, run_governed)
@@ -41,12 +42,14 @@ def run_segmented(cfg, n_seg, pad_threads=None, pad_len=None):
 
 
 class TestSegmentedParity:
-    def test_nseg_bitexact_vs_single_shot(self):
+    @each_pair
+    def test_nseg_bitexact_vs_single_shot(self, proto, kind):
         """Constant-dp segmented run == simulate() in EVERY state leaf
         and metric; only Globals.iters may grow (<= one per boundary)."""
-        cfg = EngineConfig(protocol=protocol_params("group"),
-                           costs=CostModel(), workload=ZIPF,
-                           n_threads=8, horizon=HORIZON)
+        wl, n_threads = SHAPES[kind]
+        cfg = EngineConfig(protocol=protocol_params(proto),
+                           costs=CostModel(), workload=wl,
+                           n_threads=n_threads, horizon=HORIZON)
         stat, dp = split_config(cfg)
         ref = E._run_dyn(stat, dp, E.init_state_dyn(stat, dp))
         n_seg = 5

@@ -1,7 +1,7 @@
-"""Per-kernel shape/dtype sweeps against the pure-jnp oracles
-(interpret=True executes the Pallas kernel bodies on CPU; the grouped
-scatter kernel defaults to the compiled TPU kernel, so these tests ask
-for the interpreter themselves)."""
+"""Grouped-scatter kernel shape/dtype sweeps against the pure-jnp oracles
+(interpret=True executes the Pallas kernel bodies on CPU; the kernel
+defaults to the compiled TPU kernel, so these tests ask for the
+interpreter themselves)."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -9,7 +9,6 @@ import pytest
 from repro.kernels.grouped_scatter import (segment_sums, segment_sums_ref,
                                            grouped_scatter_apply,
                                            grouped_apply_ref)
-from repro.kernels.flash_attention import flash_attention, attention_ref
 
 RNG = np.random.default_rng(42)
 
@@ -56,45 +55,3 @@ class TestGroupedScatter:
         want = grouped_apply_ref(table, ids, upd)
         # tolerance sized for f32 accumulation-order drift at 1800 adds/key
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-
-class TestFlashAttention:
-    @pytest.mark.parametrize("shape", [
-        (2, 64, 64, 4, 2, 32),      # GQA
-        (1, 128, 128, 8, 8, 64),    # MHA
-        (2, 96, 96, 6, 1, 16),      # MQA
-        (1, 256, 256, 2, 2, 128),   # long-ish
-    ])
-    @pytest.mark.parametrize("dtype", [np.float32, "bfloat16"])
-    def test_sweep_vs_ref(self, shape, dtype):
-        B, Sq, Sk, H, K, D = shape
-        dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
-        q = jnp.asarray(RNG.normal(size=(B, Sq, H, D)), dt)
-        k = jnp.asarray(RNG.normal(size=(B, Sk, K, D)), dt)
-        v = jnp.asarray(RNG.normal(size=(B, Sk, K, D)), dt)
-        got = flash_attention(q, k, v, causal=True)
-        want = attention_ref(q, k, v, causal=True)
-        tol = 2e-6 if dt == jnp.float32 else 2e-2
-        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
-
-    def test_noncausal(self):
-        q = jnp.asarray(RNG.normal(size=(1, 64, 2, 32)), jnp.float32)
-        k = jnp.asarray(RNG.normal(size=(1, 64, 2, 32)), jnp.float32)
-        v = jnp.asarray(RNG.normal(size=(1, 64, 2, 32)), jnp.float32)
-        np.testing.assert_allclose(
-            flash_attention(q, k, v, causal=False),
-            attention_ref(q, k, v, causal=False), rtol=2e-6, atol=2e-6)
-
-    def test_matches_model_attention_path(self):
-        """The kernel slot in gqa_attend agrees with the jnp path."""
-        import dataclasses
-        import jax
-        from repro.configs import get_config
-        from repro.models.attention import gqa_spec, gqa_attend
-        from repro.models.common import init_params
-        cfg = get_config("deepseek-coder-33b", smoke=True)
-        p = init_params(gqa_spec(cfg), __import__("jax").random.PRNGKey(0))
-        x = jnp.asarray(RNG.normal(size=(2, 64, cfg.d_model)), jnp.float32)
-        a, _ = gqa_attend(p, x, cfg, "global", "train", use_kernel=False)
-        b, _ = gqa_attend(p, x, cfg, "global", "train", use_kernel=True)
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)

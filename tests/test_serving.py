@@ -1,7 +1,6 @@
 """Serving-layer tests: differential parity against the closed loop,
 analytic M/M/c validation (Thomasian, arXiv:2404.02276), open-system
-invariants (property tests), compile discipline, and the first coverage
-for the dormant LM-decode GroupServer shell.
+invariants (property tests) and compile discipline.
 
 Parity standard (same bar test_sweep.py holds the sweep substrate to):
 with a saturating schedule and unbinding credit quotas, the serving path
@@ -483,23 +482,3 @@ class TestParamMemo:
         for a, b in zip(jax.tree_util.tree_leaves(memo.states["p"]),
                         jax.tree_util.tree_leaves(fresh.states["p"])):
             assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
-# ---------------------------------------------------------------------------
-# the dormant LM-decode GroupServer (launch/serve.py)
-# ---------------------------------------------------------------------------
-
-class TestGroupServerSmoke:
-    def test_serve_demo_invariants(self):
-        from repro.launch.serve import serve_demo
-        srv = serve_demo(n_requests=4, batch_slots=2)
-        # every request ran to completion and left its slot
-        assert all(r is None for r in srv.active)
-        assert not srv.queue
-        # max_new = 4 + rid % 5 for rid in 0..3 => 4+5+6+7 tokens total
-        assert srv.members_served == 22
-        # a step serves at most batch_slots members, at least one
-        assert srv.steps_fired >= 11        # ceil(22 / 2 slots)
-        assert srv.steps_fired <= 22
-        eff = srv.members_served / srv.steps_fired
-        assert 1.0 <= eff <= 2.0

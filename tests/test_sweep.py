@@ -13,8 +13,9 @@ import warnings
 import numpy as np
 import pytest
 
-from repro.core.lock import (CostModel, WorkloadSpec, extract, extract_aria,
-                             simulate, simulate_aria)
+from lock_matrix import SHAPES, each_kind
+from repro.core.lock import (PROTOCOLS, CostModel, WorkloadSpec, extract,
+                             extract_aria, simulate, simulate_aria)
 from repro.sweep import (expand, grid, load_results, point, run_sweep,
                          save_results, summarize, zip_grid)
 
@@ -79,6 +80,20 @@ class TestParity:
         res = run_sweep(pts, chunk_size=2, thread_bucket="max")
         assert len(res.buckets) == 1
         assert res.buckets[0].pad_len == 4
+        for p in pts:
+            assert_bitexact(res[p.name], reference(p), p.name)
+
+    @each_kind
+    def test_protocol_lanes_match_simulate_bitexact(self, kind):
+        """Lanes of all six tick-engine protocols in ONE vmapped program
+        (sort-then-cut, one executable per kind) equal their per-config
+        ``simulate()`` runs bit-for-bit: the protocol branches lower to
+        selects under vmap, so every lane runs every family's code."""
+        wl, n_threads = SHAPES[kind]
+        pts = grid(list(PROTOCOLS), wl, [n_threads], horizon=HORIZON,
+                   p_abort=0.1, name_fmt="{protocol}")
+        res = run_sweep(pts, chunk_size=len(pts), compact=False)
+        assert [b.n_chunks for b in res.buckets] == [1]
         for p in pts:
             assert_bitexact(res[p.name], reference(p), p.name)
 
